@@ -1,6 +1,7 @@
-"""Kernel micro-benchmarks on this host (reference path, jitted) +
-interpret-mode correctness deltas. On the TPU target the pallas path
-replaces the reference implementations via kernels.ops.set_mode('tpu')."""
+"""Kernel micro-benchmarks of the jitted jnp references on the host CPU,
+plus interpret-mode correctness deltas. Host-only: these times say
+nothing about the chip. On a TPU backend ``kernels.ops`` runs the
+compiled Pallas kernels instead; no setting selects them."""
 from __future__ import annotations
 
 import jax

@@ -43,6 +43,8 @@ def main() -> None:
                     help="write machine-readable results to PATH")
     args = ap.parse_args()
 
+    from repro.config import use_compile_cache
+    use_compile_cache()
     from benchmarks import (bench_kernels, bench_pool, bench_step, common,
                             fig6_transcoding, fig7_proportionality,
                             fig8_hw_codec, fig11_dl_serving,

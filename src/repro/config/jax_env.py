@@ -1,29 +1,36 @@
-"""JAX environment knobs for the fleet engine and batched sweeps.
+"""JAX process settings: 64-bit arithmetic, host devices, compile cache.
 
-Two process-level switches the ``backend="jax"`` fleet engine depends
-on (the idiom mirrors the elisa/numpyro helpers catalogued in
-SNIPPETS.md 1-2):
+Nothing here runs at import time; each helper is called by the entry point
+that needs it.
 
-* :func:`jax_enable_x64` — flip the global float64 flag. The jax fleet
-  engine is tolerance-parity against the float64 numpy vector engine,
-  so running it in jax's default float32 silently quadruples the error;
-  the engine also wraps its own entry points in the scoped
-  ``jax.experimental.enable_x64`` context, so this global helper is for
-  scripts/CI that want the whole process in x64 (equivalently set
-  ``JAX_ENABLE_X64=1`` before the first jax import).
+* :func:`jax_enable_x64` — flip the global float64 flag for the whole
+  process (equivalently set ``JAX_ENABLE_X64=1`` before the first jax
+  import).
+* :func:`x64_scope` — float64 for the body of a ``with`` block only. The
+  jax fleet engine is tolerance-parity against the float64 numpy vector
+  engine, so it wraps each of its entry points in this scope; in jax's
+  default float32 its drain recurrence loses request mass.
 * :func:`set_host_device_count` — make XLA expose ``n`` virtual CPU
   devices (``--xla_force_host_platform_device_count``) so a batched
   ``sweep()`` can shard its config axis with ``pmap``. Must run before
   jax initializes its backends; calling it later changes nothing for
   the current process (equivalently export
   ``XLA_FLAGS=--xla_force_host_platform_device_count=N``).
+* :func:`use_compile_cache` — keep compiled programs in JAX's persistent
+  cache: in ``$JAX_COMPILATION_CACHE_DIR`` where that is set, and
+  otherwise in ``.jax_cache`` at the root of the checkout. The path is
+  part of the cache's key, so it is fixed.
 """
 from __future__ import annotations
 
 import os
 import re
+from pathlib import Path
 
-__all__ = ["jax_enable_x64", "set_host_device_count"]
+__all__ = ["jax_enable_x64", "set_host_device_count", "use_compile_cache",
+           "x64_scope"]
+
+_CHECKOUT_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def jax_enable_x64(enable: bool = True) -> None:
@@ -31,6 +38,13 @@ def jax_enable_x64(enable: bool = True) -> None:
     import jax
 
     jax.config.update("jax_enable_x64", enable)
+
+
+def x64_scope():
+    """Context manager: 64-bit jax arithmetic inside the block."""
+    import jax
+
+    return jax.enable_x64(True)
 
 
 def set_host_device_count(n: int) -> None:
@@ -47,3 +61,17 @@ def set_host_device_count(n: int) -> None:
     os.environ["XLA_FLAGS"] = " ".join(
         [f"--xla_force_host_platform_device_count={int(n)}", *rest]
     )
+
+
+def use_compile_cache() -> str:
+    """Return the persistent compilation cache's directory:
+    ``$JAX_COMPILATION_CACHE_DIR``, which JAX reads itself, if set;
+    otherwise point JAX at the checkout's ``.jax_cache``. Call it before
+    the first compile."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(_CHECKOUT_CACHE))
+    return str(_CHECKOUT_CACHE)

@@ -23,10 +23,10 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.distributed.collectives import naive_ag_matmul, ring_ag_matmul
-from repro.distributed.compat import shard_map
 
 
 # ---------------------------------------------------------------------------

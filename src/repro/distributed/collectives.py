@@ -17,7 +17,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.distributed.compat import pvary, shard_map
+from jax import shard_map
 
 
 def _axis_size(axis_name: str) -> int:
@@ -27,8 +27,8 @@ def _axis_size(axis_name: str) -> int:
 def _pvary(x: jax.Array, axis_name: str) -> jax.Array:
     """Mark a replicated value as device-varying over `axis_name` (required
     for carries that mix with ppermute'd values under shard_map's vma type
-    system; identity on pre-vma jax)."""
-    return pvary(x, (axis_name,))
+    system)."""
+    return jax.lax.pcast(x, (axis_name,), to="varying")
 
 
 def _ring_perm(a: int) -> Sequence[tuple]:

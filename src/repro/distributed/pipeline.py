@@ -18,7 +18,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.distributed.compat import pvary, shard_map
+from jax import shard_map
 
 Params = Any
 
@@ -42,7 +42,7 @@ def pipeline_forward(stage_fn: Callable[[Params, jax.Array], jax.Array],
     ticks = m + n_stage - 1
 
     def _pvary(v):
-        return pvary(v, (axis_name,))
+        return jax.lax.pcast(v, (axis_name,), to="varying")
 
     state = _pvary(jnp.zeros_like(x_mb[0]))
     outputs = _pvary(jnp.zeros_like(x_mb))

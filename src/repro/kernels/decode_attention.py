@@ -19,6 +19,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+BLOCK_K = 256   # default KV tile; ``ops.cache_len`` sizes caches to it
 
 
 def _dec_kernel(length_ref, q_ref, k_ref, v_ref, o_ref,
@@ -68,7 +69,7 @@ def _dec_kernel(length_ref, q_ref, k_ref, v_ref, o_ref,
     jax.jit, static_argnames=("scale", "block_k", "interpret"))
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      length: jax.Array, *, scale: Optional[float] = None,
-                     block_k: int = 256, interpret: bool = False
+                     block_k: int = BLOCK_K, interpret: bool = False
                      ) -> jax.Array:
     """q: (b, hq, d); k, v: (b, skv, hkv, d); length: (b,) -> (b, hq, d)."""
     b, hq, d = q.shape
@@ -76,7 +77,10 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     g = hq // hkv
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
     block_k = min(block_k, skv)
-    assert skv % block_k == 0
+    if skv % block_k:
+        raise ValueError(
+            f"decode_attention tiles the cache length {skv} by "
+            f"block_k={block_k}; size the cache with ops.cache_len")
 
     qr = q.reshape(b * hq, 1, d)
     kr = k.transpose(0, 2, 1, 3).reshape(b * hkv, skv, d)

@@ -20,6 +20,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+BLOCK_Q = 128   # default query tile; ``ops.attention`` pads prompts to it
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -77,17 +78,22 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     static_argnames=("causal", "scale", "block_q", "block_k", "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, scale: Optional[float] = None,
-                    block_q: int = 128, block_k: int = 128,
+                    block_q: int = BLOCK_Q, block_k: int = 128,
                     interpret: bool = False) -> jax.Array:
     """q: (b, sq, hq, d); k, v: (b, skv, hkv, d) -> (b, sq, hq, d)."""
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
-    assert hq % hkv == 0
+    if hq % hkv:
+        raise ValueError(f"query heads {hq} are not a multiple of KV heads "
+                         f"{hkv}")
     g = hq // hkv
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
     block_q = min(block_q, sq)
     block_k = min(block_k, skv)
-    assert sq % block_q == 0 and skv % block_k == 0, (sq, skv, block_q, block_k)
+    if sq % block_q or skv % block_k:
+        raise ValueError(
+            f"flash_attention tiles sq={sq} by block_q={block_q} and "
+            f"skv={skv} by block_k={block_k}; pad the sequence to the block")
 
     # (b*hq, sq, d) rows; kv folded to (b*hkv, skv, d).
     qr = q.transpose(0, 2, 1, 3).reshape(b * hq, sq, d)

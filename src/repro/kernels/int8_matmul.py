@@ -47,11 +47,16 @@ def int8_matmul(x_q: jax.Array, sx: jax.Array, w_q: jax.Array,
     """x_q: (m, k) int8; sx: (m,); w_q: (k, n) int8; sw: (n,) -> (m, n)."""
     m, k = x_q.shape
     k2, n = w_q.shape
-    assert k == k2
+    if k != k2:
+        raise ValueError(f"int8_matmul: x is (m={m}, k={k}), w is "
+                         f"(k={k2}, n={n})")
     block_m = min(block_m, m)
     block_n = min(block_n, n)
     block_k = min(block_k, k)
-    assert m % block_m == 0 and n % block_n == 0 and k % block_k == 0
+    if m % block_m or n % block_n or k % block_k:
+        raise ValueError(
+            f"int8_matmul tiles (m, n, k)=({m}, {n}, {k}) by blocks "
+            f"({block_m}, {block_n}, {block_k})")
 
     grid = (m // block_m, n // block_n, k // block_k)
     return pl.pallas_call(

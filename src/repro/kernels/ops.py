@@ -1,115 +1,129 @@
-"""Jit'd dispatch wrappers for the Pallas kernels.
+"""Dispatch from the model stack to the Pallas kernels or their oracles.
 
-The model stack calls these; a process-global mode selects the backend:
+The backend decides, not a setting: on ``jax.default_backend() == "tpu"``
+every function here runs its compiled Pallas kernel (``interpret=False``);
+on every other backend it runs the pure-jnp oracle from
+:mod:`repro.kernels.ref`. Interpret mode is only the kernels' own
+``interpret=True`` argument, which the kernel tests pass.
 
-* ``reference`` (default) — pure-jnp oracles from :mod:`repro.kernels.ref`.
-  Used on CPU (this container) and for the dry-run/roofline lowering.
-* ``interpret`` — Pallas kernels executed with ``interpret=True`` (kernel
-  body runs in Python on CPU). Used by the kernel test suite.
-* ``tpu`` — Pallas kernels compiled for real TPUs (the deploy target).
+One call stays on the oracle on the TPU too: :func:`attention` with
+``kv_len`` or ``q_offset`` (masked or offset attention). The serving path
+never makes it: prefill is plain causal self-attention and decode goes
+through :func:`decode_attention`.
+
+Prefill and the SSD scan take any sequence length: the TPU branch pads
+to the kernel's tile and slices the result back.
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
+from repro.kernels.decode_attention import BLOCK_K as DECODE_BLOCK
 from repro.kernels.decode_attention import decode_attention as _pl_decode
+from repro.kernels.flash_attention import BLOCK_Q as PREFILL_BLOCK
 from repro.kernels.flash_attention import flash_attention as _pl_flash
 from repro.kernels.int8_matmul import int8_matmul as _pl_int8
 from repro.kernels.rmsnorm import rmsnorm as _pl_rmsnorm
 from repro.kernels.ssd_scan import ssd_scan as _pl_ssd
 
-_MODE = "reference"
-_VALID = ("reference", "interpret", "tpu")
+
+def on_tpu() -> bool:
+    """Whether the Pallas kernels run: the default backend is a TPU."""
+    return jax.default_backend() == "tpu"
 
 
-def set_mode(mode: str) -> None:
-    global _MODE
-    assert mode in _VALID, mode
-    _MODE = mode
-
-
-def get_mode() -> str:
-    return _MODE
-
-
-@contextlib.contextmanager
-def kernel_mode(mode: str):
-    old = _MODE
-    set_mode(mode)
-    try:
-        yield
-    finally:
-        set_mode(old)
-
-
-def _interp() -> bool:
-    return _MODE == "interpret"
+def cache_len(n: int) -> int:
+    """The decode cache length that holds ``n`` positions and tiles into
+    the decode kernel's KV blocks (``n`` itself up to one block)."""
+    if n <= DECODE_BLOCK:
+        return n
+    return -(-n // DECODE_BLOCK) * DECODE_BLOCK
 
 
 # ---------------------------------------------------------------------------
 def rmsnorm(x, w, eps: float = 1e-5, lowp: bool = False):
-    if _MODE == "reference":
-        if lowp:
-            return _ref.rmsnorm_lowp(x, w, eps)
-        return _ref.rmsnorm_ref(x, w, eps)
-    return _pl_rmsnorm(x, w, eps=eps, interpret=_interp())
+    """``lowp`` chooses between the jnp paths off the TPU."""
+    if on_tpu():
+        return _pl_rmsnorm(x, w, eps=eps)
+    if lowp:
+        return _ref.rmsnorm_lowp(x, w, eps)
+    return _ref.rmsnorm_ref(x, w, eps)
 
 
 def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
               q_offset: int = 0, kv_len=None, impl: str = "ref",
               chunk: int = 512):
-    if _MODE == "reference" or kv_len is not None or q_offset:
-        # Pallas prefill kernel covers the self-attention (no-cache) case;
-        # masked/offset variants stay on the reference path.
-        if impl.startswith("chunked") and kv_len is None and not q_offset:
-            if impl == "chunked_kvrep":
-                # GQA sharding fix for the XLA path: the (hkv, g) reshape
-                # can't shard either factor over a 16-way model axis, so
-                # scores replicate. Expanding KV to hq heads keeps the
-                # flat head dim sharded (cheap: KV is tiny next to the
-                # O(s^2) scores it de-replicates). The repeat output MUST
-                # be re-constrained or it replicates too.
-                from repro.distributed.sharding import shard as _shard
-                g = q.shape[2] // k.shape[2]
-                if g > 1:
-                    k = _shard(jnp.repeat(k, g, axis=2),
-                               ("batch", "seq", "heads_act", None))
-                    v = _shard(jnp.repeat(v, g, axis=2),
-                               ("batch", "seq", "heads_act", None))
-            return _ref.attention_chunked(q, k, v, causal=causal,
-                                          scale=scale, chunk=chunk)
+    """q: (b, s, hq, d); k, v: (b, s, hkv, d). ``impl`` and ``chunk``
+    choose among the jnp paths off the TPU."""
+    if kv_len is not None or q_offset:
         return _ref.attention_ref(q, k, v, causal=causal, scale=scale,
                                   q_offset=q_offset, kv_len=kv_len)
-    return _pl_flash(q, k, v, causal=causal, scale=scale,
-                     interpret=_interp())
+    if on_tpu():
+        # Pad the sequence to the kernel's block and slice it back. Under
+        # the causal mask the padded keys sit after every real query.
+        s = q.shape[1]
+        pad = (-s) % PREFILL_BLOCK
+        if pad and not causal:
+            raise ValueError(f"non-causal attention needs s % "
+                             f"{PREFILL_BLOCK} == 0 on the TPU, got s={s}")
+        if pad:
+            widths = ((0, 0), (0, pad), (0, 0), (0, 0))
+            q, k, v = (jnp.pad(t, widths) for t in (q, k, v))
+        return _pl_flash(q, k, v, causal=causal, scale=scale)[:, :s]
+    if impl.startswith("chunked"):
+        if impl == "chunked_kvrep":
+            # GQA sharding fix for the XLA path: the (hkv, g) reshape
+            # can't shard either factor over a 16-way model axis, so
+            # scores replicate. Expanding KV to hq heads keeps the
+            # flat head dim sharded (cheap: KV is tiny next to the
+            # O(s^2) scores it de-replicates). The repeat output MUST
+            # be re-constrained or it replicates too.
+            from repro.distributed.sharding import shard as _shard
+            g = q.shape[2] // k.shape[2]
+            if g > 1:
+                k = _shard(jnp.repeat(k, g, axis=2),
+                           ("batch", "seq", "heads_act", None))
+                v = _shard(jnp.repeat(v, g, axis=2),
+                           ("batch", "seq", "heads_act", None))
+        return _ref.attention_chunked(q, k, v, causal=causal,
+                                      scale=scale, chunk=chunk)
+    return _ref.attention_ref(q, k, v, causal=causal, scale=scale)
 
 
 def decode_attention(q, k, v, length, *, scale: Optional[float] = None,
                      impl: str = "ref"):
-    if _MODE == "reference":
-        if impl == "chunked":   # "chunked" config selects low-cast decode
-            return _ref.decode_attention_lowcast(q, k, v, length,
-                                                 scale=scale)
-        return _ref.decode_attention_ref(q, k, v, length, scale=scale)
-    return _pl_decode(q, k, v, length, scale=scale, interpret=_interp())
+    """q: (b, hq, d); k, v: (b, L, hkv, d) with L from :func:`cache_len`."""
+    if on_tpu():
+        return _pl_decode(q, k, v, length, scale=scale)
+    if impl == "chunked":   # "chunked" config selects low-cast decode
+        return _ref.decode_attention_lowcast(q, k, v, length, scale=scale)
+    return _ref.decode_attention_ref(q, k, v, length, scale=scale)
 
 
 def int8_matmul(x_q, sx, w_q, sw, out_dtype=jnp.float32):
-    if _MODE == "reference":
-        return _ref.int8_matmul_ref(x_q, sx, w_q, sw).astype(out_dtype)
-    return _pl_int8(x_q, sx, w_q, sw, out_dtype=out_dtype,
-                    interpret=_interp())
+    if on_tpu():
+        return _pl_int8(x_q, sx, w_q, sw, out_dtype=out_dtype)
+    return _ref.int8_matmul_ref(x_q, sx, w_q, sw).astype(out_dtype)
 
 
 def ssd(x, dt, A, B, C, D, *, chunk: int = 128):
     """Returns (y, final_state (b,h,p,n) fp32)."""
-    if _MODE == "reference":
+    if not on_tpu():
         return _ref.ssd_chunked(x, dt, A, B, C, D, chunk=chunk)
-    return _pl_ssd(x, dt, A, B, C, D, chunk=chunk, interpret=_interp())
+    # Padded steps have dt = 0: no decay and no input, so the real
+    # outputs and the final state are those of the unpadded sequence.
+    s = x.shape[1]
+    pad = (-s) % chunk
+    if pad:
+        x, dt, B, C = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (x, dt, B, C))
+    y, state = _pl_ssd(x, dt, A, B, C, D, chunk=chunk)
+    return y[:, :s], state
 
 
 quantize_int8 = _ref.quantize_int8

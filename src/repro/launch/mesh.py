@@ -6,13 +6,8 @@ import jax
 
 
 def _mesh(shape, axes):
-    # axis_types / AxisType landed after jax 0.4; default (Auto) semantics
-    # are what we want on both old and new jax.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

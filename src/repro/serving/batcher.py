@@ -67,7 +67,7 @@ class ContinuousBatcher:
     def _ensure_caches(self) -> None:
         if self.caches is None:
             self.caches = lm.init_caches(
-                self.cfg, self.slots, self.engine.scfg.max_seq_len)
+                self.cfg, self.slots, self.engine.max_len)
 
     def _admit(self, max_slots: Optional[int] = None) -> None:
         limit = self.slots if max_slots is None else min(max_slots,

@@ -1,9 +1,10 @@
 """Serving engine: jit'd prefill / decode with full-length caches.
 
-Decode caches live at ``max_seq_len`` from the start (the dry-run decode
-cells take them as inputs); prefill writes the first ``s`` positions and the
-engine pads. Weight-only int8 serving (the paper's DSP path) is applied at
-load time via ``ServeConfig.quantize_weights``.
+Decode caches live at ``max_len`` from the start: ``ServeConfig.max_seq_len``
+rounded up to the decode kernel's KV block (``ops.cache_len``). Prefill
+writes the first ``s`` positions and the engine pads. Weight-only int8
+serving (the paper's DSP path) is applied at load time via
+``ServeConfig.quantize_weights``.
 """
 from __future__ import annotations
 
@@ -14,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.config import ModelConfig, ServeConfig
 from repro.distributed.sharding import RuleSet, serve_rules, use_sharding
+from repro.kernels import ops
 from repro.kernels.ref import quantize_int8
 from repro.models import model as lm
 
@@ -53,6 +55,7 @@ class ServingEngine:
         self.mesh = mesh
         self.rules = rules or serve_rules(self.scfg.serve_fsdp)
         self.scan = scan
+        self.max_len = ops.cache_len(self.scfg.max_seq_len)
         self.params: Optional[Params] = None
 
         def _prefill(params, batch):
@@ -60,7 +63,7 @@ class ServingEngine:
                 if self.scfg.quantize_weights:
                     params = dequantize_params(params)
                 return lm.prefill(params, cfg, batch, scan=self.scan,
-                                  max_len=self.scfg.max_seq_len)
+                                  max_len=self.max_len)
 
         def _decode(params, tokens, caches, pos):
             with use_sharding(self.mesh, self.rules):

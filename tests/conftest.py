@@ -16,8 +16,11 @@ def run_in_subprocess(code: str, devices: int = 8, timeout: int = 600
 
     Multi-device behaviours (shard_map collectives, pipelines, meshes)
     can't run in the main pytest process, which is pinned to 1 device.
+    The child runs on the CPU: its devices are fake host devices, and a
+    chip, if there is one, belongs to the parent.
     """
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     return subprocess.run([sys.executable, "-c", code], env=env,

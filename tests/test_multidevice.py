@@ -16,7 +16,7 @@ def test_ring_collective_matmuls(subproc):
     code = """
 import jax, jax.numpy as jnp, numpy as np, functools
 from jax.sharding import PartitionSpec as P
-from repro.distributed.compat import shard_map
+from jax import shard_map
 from repro.distributed.collectives import (ring_ag_matmul, ring_matmul_rs,
                                            naive_ag_matmul, naive_matmul_rs)
 mesh = jax.make_mesh((8,), ("model",))
@@ -42,7 +42,7 @@ def test_compressed_allreduce(subproc):
     code = """
 import jax, jax.numpy as jnp, numpy as np, functools
 from jax.sharding import PartitionSpec as P
-from repro.distributed.compat import shard_map
+from jax import shard_map
 from repro.distributed.compression import compressed_psum_mean, wire_bytes_fp32, wire_bytes_compressed
 mesh = jax.make_mesh((8,), ("d",))
 rng = np.random.default_rng(0)

@@ -1,5 +1,6 @@
-"""Smoke test on one TPU: the Pallas kernels against their references,
-then internlm2-1.8b served at full width through the launcher's own code.
+"""Smoke test on one TPU: the Pallas kernels against their references, a
+training step through them, then internlm2-1.8b served at full width
+through the launcher's own code.
 
     python chip_smoke.py [--seed N]
 
@@ -9,11 +10,15 @@ a. report the device; stop with an error unless it is a TPU;
 b. run the compiled kernels at internlm2-1.8b widths (and the SSD scan at
    mamba2-130m widths) and compare each with its ``ref.*`` oracle on the
    same device;
-c. serve 8 requests of seeded prompt lengths, 32 new tokens each, on 4
+c. take one training step of internlm2-1.8b at full width, depth cut to
+   2 layers, on an unaligned sequence length: the kernels run forward and
+   their references backward. Compare its loss and gradient norm with the
+   same step on the jnp paths, on the same device;
+d. serve 8 requests of seeded prompt lengths, 32 new tokens each, on 4
    slots, with random weights made from the seed, through
    ``repro.launch.serve.serve``; check token counts, the vocabulary bound,
    and that the same prompt served twice gives the same greedy output;
-d. print the result as one JSON object on the last line.
+e. print the result as one JSON object on the last line.
 
 Any failure exits non-zero without that line. Times printed are those of a
 smoke run, compilation included, and are not measurements.
@@ -35,6 +40,7 @@ ARCH = "internlm2-1.8b"
 # bf16 outputs round at 2^-9 relative; a wrong mask or tile is O(1).
 ERR_BOUND = 2e-2
 REQUESTS, NEW_TOKENS, SLOTS = 8, 32, 4
+TRAIN_LAYERS, TRAIN_BATCH, TRAIN_SEQ = 2, 2, 300
 
 
 def fail(msg: str) -> None:
@@ -133,6 +139,55 @@ def phase_kernels(seed: int) -> None:
     _check(f"ssd_scan state s={s} h={h}", state, state_ref)
 
 
+def phase_train(seed: int) -> None:
+    import dataclasses
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.config import TrainConfig, get_config
+    from repro.kernels import ops
+    from repro.models import model as lm
+    from repro.training.optimizer import init_opt_state
+    from repro.training.train_loop import jit_train_step
+
+    cfg = dataclasses.replace(get_config(ARCH), num_layers=TRAIN_LAYERS)
+    tcfg = TrainConfig(warmup_steps=1, remat="none")
+    params = lm.init_params(cfg, jax.random.key(seed))
+    opt = init_opt_state(params, tcfg)
+    shape = (TRAIN_BATCH, TRAIN_SEQ)
+    k_tok, k_lab = jax.random.split(jax.random.fold_in(jax.random.key(seed),
+                                                       1))
+    batch = {"tokens": jax.random.randint(k_tok, shape, 0, cfg.vocab_size),
+             "labels": jax.random.randint(k_lab, shape, 0, cfg.vocab_size),
+             "mask": jnp.ones(shape, jnp.int32)}
+
+    def step():
+        # The updated state is dropped on return: two of it do not fit.
+        new, _, metrics = jit_train_step(cfg, tcfg, None, donate=False)(
+            params, opt, batch)
+        if not all(bool(jnp.isfinite(p).all()) for p in jax.tree.leaves(new)):
+            fail("train step: non-finite parameters after the update")
+        return {k: float(metrics[k]) for k in ("loss", "grad_norm")}
+
+    t0 = time.monotonic()
+    got = step()
+    wall = time.monotonic() - t0
+    with mock.patch.object(ops, "on_tpu", lambda: False):
+        want = step()
+    print(f"train step: {cfg.name} layers={cfg.num_layers} "
+          f"d_model={cfg.d_model} batch={shape} wall_s={wall:.1f} "
+          f"(smoke run incl. compilation; not a measurement)", flush=True)
+    for k in ("loss", "grad_norm"):
+        err = abs(got[k] - want[k]) / abs(want[k])
+        print(f"train {k}: kernels {got[k]:.6f} jnp paths {want[k]:.6f} "
+              f"rel_err={err:.3e} (bound {ERR_BOUND})", flush=True)
+        if not np.isfinite(got[k]) or err > ERR_BOUND:
+            fail(f"train step: {k} {got[k]} against {want[k]} on the jnp "
+                 f"paths")
+
+
 def phase_serve(seed: int) -> None:
     from repro.config import get_config
     from repro.launch.serve import serve
@@ -187,6 +242,7 @@ def main() -> None:
     print(f"compile cache: {use_compile_cache()}", flush=True)
     device = phase_device()
     phase_kernels(args.seed)
+    phase_train(args.seed)
     phase_serve(args.seed)
     print(json.dumps({"ok": True, "device": device}), flush=True)
 

@@ -5,11 +5,8 @@ that needs it.
 
 * :func:`jax_enable_x64` — flip the global float64 flag for the whole
   process (equivalently set ``JAX_ENABLE_X64=1`` before the first jax
-  import).
-* :func:`x64_scope` — float64 for the body of a ``with`` block only. The
-  jax fleet engine is tolerance-parity against the float64 numpy vector
-  engine, so it wraps each of its entry points in this scope; in jax's
-  default float32 its drain recurrence loses request mass.
+  import). The jax fleet engine needs no global flag: it scopes float64
+  to its own entry points with ``jax.enable_x64(True)``.
 * :func:`set_host_device_count` — make XLA expose ``n`` virtual CPU
   devices (``--xla_force_host_platform_device_count``) so a batched
   ``sweep()`` can shard its config axis with ``pmap``. Must run before
@@ -27,8 +24,7 @@ import os
 import re
 from pathlib import Path
 
-__all__ = ["jax_enable_x64", "set_host_device_count", "use_compile_cache",
-           "x64_scope"]
+__all__ = ["jax_enable_x64", "set_host_device_count", "use_compile_cache"]
 
 _CHECKOUT_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
 
@@ -38,13 +34,6 @@ def jax_enable_x64(enable: bool = True) -> None:
     import jax
 
     jax.config.update("jax_enable_x64", enable)
-
-
-def x64_scope():
-    """Context manager: 64-bit jax arithmetic inside the block."""
-    import jax
-
-    return jax.enable_x64(True)
 
 
 def set_host_device_count(n: int) -> None:

@@ -19,7 +19,7 @@ reproduces the same arithmetic but XLA may fuse (FMA), reassociate
 pairwise reductions, and schedule segment ops differently, so its
 telemetry is compared against the vector engine under documented
 rtol/atol bounds (``tests/test_jax_parity.py``). Float64 is mandatory:
-every entry point runs inside ``repro.config.jax_env.x64_scope`` — in
+every entry point runs inside ``jax.enable_x64(True)`` — in
 default float32 the drain recurrence loses request mass far beyond
 those bounds.
 
@@ -59,7 +59,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.config.jax_env import x64_scope
 from repro.fleet.degrade import BRK_HALF, BRK_OPEN
 from repro.fleet.engine_state import (
     GOV_FIXED,
@@ -1307,7 +1306,7 @@ class _JaxFleetEngine:
         with one row per simulated tick; ``drained`` is ``None`` when
         the call simulated no ticks at all.
         """
-        with x64_scope():
+        with jax.enable_x64(True):
             return self._play(np.asarray(trace_rps, float), drain)
 
     def _play(
@@ -1824,7 +1823,7 @@ def sweep(
     trace = np.asarray(trace_rps, float)
     assert len(configs) > 0, "need at least one sweep config"
     assert len(trace) > 0, "need a non-empty trace"
-    with x64_scope():
+    with jax.enable_x64(True):
         return _sweep(racks, list(configs), trace, dt_s, idle_units_off,
                       drain_ticks)
 

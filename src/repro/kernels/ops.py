@@ -6,21 +6,35 @@ on every other backend it runs the pure-jnp oracle from
 :mod:`repro.kernels.ref`. Interpret mode is only the kernels' own
 ``interpret=True`` argument, which the kernel tests pass.
 
-One call stays on the oracle on the TPU too: :func:`attention` with
-``kv_len`` or ``q_offset`` (masked or offset attention). The serving path
-never makes it: prefill is plain causal self-attention and decode goes
-through :func:`decode_attention`.
+Two cases stay on the jnp paths on the TPU too:
+
+* :func:`attention` with ``kv_len`` or ``q_offset`` (masked or offset
+  attention). The serving path never makes it: prefill is plain causal
+  self-attention and decode goes through :func:`decode_attention`.
+* a program traced under a sharding mesh of more than one device
+  (:func:`repro.distributed.sharding.use_sharding`). The kernels are not
+  wrapped in ``shard_map``, so a sharded program keeps the XLA paths and
+  their layouts (``impl="chunked_kvrep"`` included). On a single chip,
+  ``impl`` and ``chunk`` do nothing.
 
 Prefill and the SSD scan take any sequence length: the TPU branch pads
 to the kernel's tile and slices the result back.
+
+A ``pallas_call`` has a JVP but no transpose rule, so every kernel here
+is differentiated as its oracle: the backward pass recomputes the
+``ref.*`` function on the same inputs and takes its VJP. Training on the
+TPU runs the kernels forward and the oracles backward.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.distributed.sharding import active_mesh
+from repro.distributed.sharding import shard as _shard
 from repro.kernels import ref as _ref
 from repro.kernels.decode_attention import BLOCK_K as DECODE_BLOCK
 from repro.kernels.decode_attention import decode_attention as _pl_decode
@@ -32,8 +46,28 @@ from repro.kernels.ssd_scan import ssd_scan as _pl_ssd
 
 
 def on_tpu() -> bool:
-    """Whether the Pallas kernels run: the default backend is a TPU."""
-    return jax.default_backend() == "tpu"
+    """Whether the Pallas kernels run: the default backend is a TPU and
+    no mesh of more than one device is active."""
+    if jax.default_backend() != "tpu":
+        return False
+    mesh = active_mesh()
+    return mesh is None or mesh.size == 1
+
+
+def _grad_by_ref(kernel, reference, *args):
+    """``kernel(*args)``, differentiated as ``reference(*args)``."""
+    @jax.custom_vjp
+    def f(*a):
+        return kernel(*a)
+
+    def fwd(*a):
+        return kernel(*a), a
+
+    def bwd(a, g):
+        return jax.vjp(reference, *a)[1](g)
+
+    f.defvjp(fwd, bwd)
+    return f(*args)
 
 
 def cache_len(n: int) -> int:
@@ -48,7 +82,9 @@ def cache_len(n: int) -> int:
 def rmsnorm(x, w, eps: float = 1e-5, lowp: bool = False):
     """``lowp`` chooses between the jnp paths off the TPU."""
     if on_tpu():
-        return _pl_rmsnorm(x, w, eps=eps)
+        return _grad_by_ref(functools.partial(_pl_rmsnorm, eps=eps),
+                            functools.partial(_ref.rmsnorm_ref, eps=eps),
+                            x, w)
     if lowp:
         return _ref.rmsnorm_lowp(x, w, eps)
     return _ref.rmsnorm_ref(x, w, eps)
@@ -58,7 +94,7 @@ def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
               q_offset: int = 0, kv_len=None, impl: str = "ref",
               chunk: int = 512):
     """q: (b, s, hq, d); k, v: (b, s, hkv, d). ``impl`` and ``chunk``
-    choose among the jnp paths off the TPU."""
+    choose among the jnp paths; the Pallas kernel ignores them."""
     if kv_len is not None or q_offset:
         return _ref.attention_ref(q, k, v, causal=causal, scale=scale,
                                   q_offset=q_offset, kv_len=kv_len)
@@ -73,7 +109,10 @@ def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
         if pad:
             widths = ((0, 0), (0, pad), (0, 0), (0, 0))
             q, k, v = (jnp.pad(t, widths) for t in (q, k, v))
-        return _pl_flash(q, k, v, causal=causal, scale=scale)[:, :s]
+        return _grad_by_ref(
+            functools.partial(_pl_flash, causal=causal, scale=scale),
+            functools.partial(_ref.attention_ref, causal=causal, scale=scale),
+            q, k, v)[:, :s]
     if impl.startswith("chunked"):
         if impl == "chunked_kvrep":
             # GQA sharding fix for the XLA path: the (hkv, g) reshape
@@ -82,7 +121,6 @@ def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
             # flat head dim sharded (cheap: KV is tiny next to the
             # O(s^2) scores it de-replicates). The repeat output MUST
             # be re-constrained or it replicates too.
-            from repro.distributed.sharding import shard as _shard
             g = q.shape[2] // k.shape[2]
             if g > 1:
                 k = _shard(jnp.repeat(k, g, axis=2),
@@ -98,7 +136,10 @@ def decode_attention(q, k, v, length, *, scale: Optional[float] = None,
                      impl: str = "ref"):
     """q: (b, hq, d); k, v: (b, L, hkv, d) with L from :func:`cache_len`."""
     if on_tpu():
-        return _pl_decode(q, k, v, length, scale=scale)
+        return _grad_by_ref(
+            functools.partial(_pl_decode, scale=scale),
+            functools.partial(_ref.decode_attention_ref, scale=scale),
+            q, k, v, length)
     if impl == "chunked":   # "chunked" config selects low-cast decode
         return _ref.decode_attention_lowcast(q, k, v, length, scale=scale)
     return _ref.decode_attention_ref(q, k, v, length, scale=scale)
@@ -106,7 +147,10 @@ def decode_attention(q, k, v, length, *, scale: Optional[float] = None,
 
 def int8_matmul(x_q, sx, w_q, sw, out_dtype=jnp.float32):
     if on_tpu():
-        return _pl_int8(x_q, sx, w_q, sw, out_dtype=out_dtype)
+        return _grad_by_ref(
+            functools.partial(_pl_int8, out_dtype=out_dtype),
+            lambda *a: _ref.int8_matmul_ref(*a).astype(out_dtype),
+            x_q, sx, w_q, sw)
     return _ref.int8_matmul_ref(x_q, sx, w_q, sw).astype(out_dtype)
 
 
@@ -122,7 +166,9 @@ def ssd(x, dt, A, B, C, D, *, chunk: int = 128):
         x, dt, B, C = (
             jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
             for t in (x, dt, B, C))
-    y, state = _pl_ssd(x, dt, A, B, C, D, chunk=chunk)
+    y, state = _grad_by_ref(functools.partial(_pl_ssd, chunk=chunk),
+                            functools.partial(_ref.ssd_chunked, chunk=chunk),
+                            x, dt, A, B, C, D)
     return y[:, :s], state
 
 

@@ -1,5 +1,5 @@
-"""Process settings from ``repro.config.jax_env``: where the compile cache
-goes, and the scoped float64 the jax fleet engine runs under."""
+"""Process settings: where ``repro.config.jax_env`` puts the compile cache,
+and the scoped float64 the jax fleet engine runs under."""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -33,8 +33,20 @@ def test_compile_cache_fixed_in_checkout(monkeypatch, restore_cache_dir):
     assert ".jax_cache/" in gitignore
 
 
-def test_x64_scope_is_scoped():
+def test_x64_scope_is_scoped(monkeypatch):
+    """The jax fleet engine's entry points compute in float64 and leave the
+    process in jax's default float32."""
+    from repro.fleet import jax_engine
+    seen = []
+
+    def probe(*args):
+        seen.append(jnp.zeros(1).dtype)
+
+    monkeypatch.setattr(jax_engine._JaxFleetEngine, "_play", probe)
+    monkeypatch.setattr(jax_engine, "_sweep", probe)
     assert jnp.zeros(1).dtype == jnp.float32
-    with jax_env.x64_scope():
-        assert jnp.zeros(1).dtype == jnp.float64
+    engine = object.__new__(jax_engine._JaxFleetEngine)
+    engine.play([1.0])
+    jax_engine.sweep([], [object()], [1.0])
+    assert seen == [jnp.float64, jnp.float64]
     assert jnp.zeros(1).dtype == jnp.float32

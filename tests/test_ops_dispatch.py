@@ -2,6 +2,7 @@
 branch pads sequences the kernels cannot tile (kernels in interpret mode,
 on the CPU)."""
 import functools
+import types
 
 import jax
 import jax.numpy as jnp
@@ -47,6 +48,10 @@ def _ops_calls(rng):
 
 
 OPS = ["rmsnorm", "attention", "decode_attention", "int8_matmul", "ssd"]
+# The floating-point arguments of each call, which a gradient can take.
+FLOAT_ARGS = {"rmsnorm": (0, 1), "attention": (0, 1, 2),
+              "decode_attention": (0, 1, 2), "int8_matmul": (1, 3),
+              "ssd": (0, 1, 2, 3, 4, 5)}
 
 
 @pytest.mark.parametrize("name", OPS)
@@ -67,6 +72,41 @@ def test_dispatch_follows_backend(name, platform, backend, monkeypatch, rng):
     assert calls == ([name] if platform == "tpu" else [])
     assert all(np.isfinite(np.asarray(o)).all()
                for o in jax.tree.leaves(out))
+
+
+@pytest.mark.parametrize("name", OPS)
+def test_grad_through_kernel_is_reference_grad(name, backend, monkeypatch,
+                                               rng):
+    """A ``pallas_call`` cannot be transposed; on the TPU branch the
+    gradient is the oracle's, so training works there too."""
+    fn, args, kwargs, pallas = _ops_calls(rng)[name]
+    monkeypatch.setattr(ops, pallas, functools.partial(getattr(ops, pallas),
+                                                       interpret=True))
+
+    def loss(*a):
+        return sum(jnp.sum(o.astype(jnp.float32))
+                   for o in jax.tree.leaves(fn(*a, **kwargs)))
+
+    grad = jax.grad(loss, argnums=FLOAT_ARGS[name])
+    backend("tpu")
+    got = grad(*args)
+    backend("cpu")
+    want = grad(*args)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("devices,pallas", [(1, True), (4, False)])
+def test_sharded_program_keeps_xla_paths(devices, pallas, backend,
+                                         monkeypatch):
+    """The kernels are not wrapped in ``shard_map``: under a mesh of more
+    than one device the TPU keeps the XLA paths and their layouts."""
+    monkeypatch.setattr(ops, "active_mesh",
+                        lambda: types.SimpleNamespace(size=devices))
+    backend("tpu")
+    assert ops.on_tpu() is pallas
 
 
 def test_masked_attention_stays_on_reference(backend, monkeypatch, rng):

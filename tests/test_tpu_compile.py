@@ -7,18 +7,22 @@ topology and asserts that the Pallas kernel is in the program
 the fixtures below, never at import time, so that under several pytest
 workers only the worker given this file loads the TPU compiler.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.config import get_config
+from repro.config import TrainConfig, get_config
 from repro.kernels import ops
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rmsnorm import rmsnorm
 from repro.kernels.ssd_scan import ssd_scan
 from repro.models import model as lm
+from repro.training.optimizer import init_opt_state
+from repro.training.train_loop import make_train_step
 
 ARCH = "internlm2-1.8b"
 
@@ -39,11 +43,8 @@ def no_persistent_cache():
 @pytest.fixture(scope="module")
 def topo(no_persistent_cache):
     from jax.experimental import topologies
-    try:
-        return topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
 
 
 @pytest.fixture(scope="module")
@@ -139,4 +140,20 @@ def test_prefill_compiles_at_unaligned_length(tpu_branch, one_chip, spec):
         return lm.prefill(params, cfg, {"tokens": tokens}, max_len=512)
 
     text = _compiled_text(prefill, params, spec((1, 342), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_train_step_compiles(tpu_branch, one_chip, spec):
+    """Reverse mode through the kernels (full width, depth cut to two
+    layers, an unaligned sequence length)."""
+    cfg = dataclasses.replace(get_config(ARCH), num_layers=2)
+    tcfg = TrainConfig()
+    params = lm.param_shapes(cfg)
+    opt = _placed(jax.eval_shape(lambda p: init_opt_state(p, tcfg), params),
+                  one_chip)
+    batch = {"tokens": spec((1, 300), jnp.int32),
+             "labels": spec((1, 300), jnp.int32),
+             "mask": spec((1, 300), jnp.int32)}
+    text = _compiled_text(make_train_step(cfg, tcfg),
+                          _placed(params, one_chip), opt, batch)
     assert "tpu_custom_call" in text

@@ -82,12 +82,15 @@ def _project_qkv(params: Params, cfg: ModelConfig, x: jax.Array):
     return q, k, v
 
 
+@jax.named_scope("attn")
 def attn_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
                mode: str, cache: Optional[Params] = None,
                pos: Optional[jax.Array] = None,
                max_len: Optional[int] = None
                ) -> Tuple[jax.Array, Optional[Params]]:
-    """x: (b, s, d). Returns (out, new_cache)."""
+    """x: (b, s, d). Returns (out, new_cache). Named scopes: ``attn``
+    around it all, ``kv_write`` around the cache writes and ``attn_core``
+    around the attention kernel call."""
     b, s, d = x.shape
     if mode in ("train", "prefill"):
         positions = jnp.arange(s)
@@ -95,18 +98,20 @@ def attn_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
         q, k, v = _project_qkv(params, cfg, x)
         q = rope_apply(q, sin, cos)
         k = rope_apply(k, sin, cos)
-        out = ops.attention(q, k, v, causal=True, impl=cfg.attn_impl,
-                            chunk=cfg.attn_chunk)
+        with jax.named_scope("attn_core"):
+            out = ops.attention(q, k, v, causal=True, impl=cfg.attn_impl,
+                                chunk=cfg.attn_chunk)
         new_cache = None
         if mode == "prefill":
-            kc, vc = k, v
-            if max_len is not None and max_len > s:
-                pad = ((0, 0), (0, max_len - s), (0, 0), (0, 0))
-                kc, vc = jnp.pad(kc, pad), jnp.pad(vc, pad)
-            new_cache = {
-                "k": shard(kc, ("batch", "kv_seq", "kv_heads_act", None)),
-                "v": shard(vc, ("batch", "kv_seq", "kv_heads_act", None)),
-            }
+            with jax.named_scope("kv_write"):
+                kc, vc = k, v
+                if max_len is not None and max_len > s:
+                    pad = ((0, 0), (0, max_len - s), (0, 0), (0, 0))
+                    kc, vc = jnp.pad(kc, pad), jnp.pad(vc, pad)
+                new_cache = {
+                    "k": shard(kc, ("batch", "kv_seq", "kv_heads_act", None)),
+                    "v": shard(vc, ("batch", "kv_seq", "kv_heads_act", None)),
+                }
     else:  # decode
         assert cache is not None and pos is not None
         pos_arr = jnp.asarray(pos)
@@ -122,8 +127,11 @@ def attn_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
             q = rope_apply(q, sin, cos)
             k = rope_apply(k, sin, cos)
             bidx = jnp.arange(b)
-            k_cache = cache["k"].at[bidx, pos_arr].set(k[:, 0].astype(cdt))
-            v_cache = cache["v"].at[bidx, pos_arr].set(v[:, 0].astype(cdt))
+            with jax.named_scope("kv_write"):
+                k_cache = cache["k"].at[bidx, pos_arr].set(
+                    k[:, 0].astype(cdt))
+                v_cache = cache["v"].at[bidx, pos_arr].set(
+                    v[:, 0].astype(cdt))
             length = pos_arr.astype(jnp.int32) + 1
         else:
             positions = pos_arr.reshape(1)
@@ -131,15 +139,17 @@ def attn_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
                                   cfg.rope_theta)
             q = rope_apply(q, sin, cos)
             k = rope_apply(k, sin, cos)
-            k_cache = jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], k.astype(cdt), pos, axis=1)
-            v_cache = jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], v.astype(cdt), pos, axis=1)
+            with jax.named_scope("kv_write"):
+                k_cache = jax.lax.dynamic_update_slice_in_dim(
+                    cache["k"], k.astype(cdt), pos, axis=1)
+                v_cache = jax.lax.dynamic_update_slice_in_dim(
+                    cache["v"], v.astype(cdt), pos, axis=1)
             length = jnp.full((b,), pos_arr + 1, jnp.int32)
         k_cache = shard(k_cache, ("batch", "kv_seq", "kv_heads_act", None))
         v_cache = shard(v_cache, ("batch", "kv_seq", "kv_heads_act", None))
-        out1 = ops.decode_attention(q[:, 0], k_cache, v_cache, length,
-                                    impl=cfg.attn_impl)
+        with jax.named_scope("attn_core"):
+            out1 = ops.decode_attention(q[:, 0], k_cache, v_cache, length,
+                                        impl=cfg.attn_impl)
         out = out1[:, None]
         new_cache = {"k": k_cache, "v": v_cache}
     out = shard(out, ("batch", "seq", "heads_act", None))

@@ -55,11 +55,12 @@ def param_shapes(cfg: ModelConfig) -> Params:
 # ---------------------------------------------------------------------------
 def _embed_inputs(params: Params, cfg: ModelConfig, batch: Dict[str, Any]
                   ) -> jax.Array:
-    x = embed_apply(params["embed"], batch["tokens"])
-    if "vision_embeds" in batch and batch["vision_embeds"] is not None:
-        ve = batch["vision_embeds"].astype(x.dtype)
-        x = jnp.concatenate([ve, x], axis=1)
-        x = shard(x, ("batch", "seq", "embed_act"))
+    with jax.named_scope("embed"):
+        x = embed_apply(params["embed"], batch["tokens"])
+        if "vision_embeds" in batch and batch["vision_embeds"] is not None:
+            ve = batch["vision_embeds"].astype(x.dtype)
+            x = jnp.concatenate([ve, x], axis=1)
+            x = shard(x, ("batch", "seq", "embed_act"))
     return x
 
 
@@ -73,10 +74,12 @@ def forward(params: Params, cfg: ModelConfig, batch: Dict[str, Any], *,
     x, new_caches, aux = stack.stack_apply(
         params["blocks"], cfg, x, mode=mode, caches=caches, pos=pos,
         scan=scan, remat=remat, max_len=max_len)
-    x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps,
-                      lowp=cfg.mlp_lowp)
-    logits = unembed_apply(params["embed"] if cfg.tie_embeddings
-                           else {**params["embed"]}, x)
+    with jax.named_scope("norm"):
+        x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps,
+                          lowp=cfg.mlp_lowp)
+    with jax.named_scope("lm_head"):
+        logits = unembed_apply(params["embed"] if cfg.tie_embeddings
+                               else {**params["embed"]}, x)
     return logits, new_caches, aux
 
 

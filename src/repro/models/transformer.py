@@ -84,8 +84,9 @@ def layer_apply(params: Params, cfg: ModelConfig, i_sig: Tuple[str, str],
                 pos, max_len: Optional[int] = None
                 ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
     kind, ffn = i_sig
-    h = rmsnorm_apply(params["norm1"], x, cfg.norm_eps,
-                      lowp=cfg.mlp_lowp)
+    with jax.named_scope("norm"):
+        h = rmsnorm_apply(params["norm1"], x, cfg.norm_eps,
+                          lowp=cfg.mlp_lowp)
     if kind == ATTN:
         mix, new_cache = attn_mod.attn_apply(
             params["mixer"], cfg, h, mode=mode, cache=cache, pos=pos,
@@ -96,12 +97,14 @@ def layer_apply(params: Params, cfg: ModelConfig, i_sig: Tuple[str, str],
     x = x + mix
     aux = jnp.zeros((), jnp.float32)
     if ffn != "none":
-        h = rmsnorm_apply(params["norm2"], x, cfg.norm_eps,
-                          lowp=cfg.mlp_lowp)
-        if ffn == "moe":
-            f, aux = moe_mod.moe_apply(params["ffn"], cfg, h)
-        else:
-            f = mlp_apply(params["ffn"], h, lowp=cfg.mlp_lowp)
+        with jax.named_scope("norm"):
+            h = rmsnorm_apply(params["norm2"], x, cfg.norm_eps,
+                              lowp=cfg.mlp_lowp)
+        with jax.named_scope("moe" if ffn == "moe" else "mlp"):
+            if ffn == "moe":
+                f, aux = moe_mod.moe_apply(params["ffn"], cfg, h)
+            else:
+                f = mlp_apply(params["ffn"], h, lowp=cfg.mlp_lowp)
         x = x + f
     x = shard(x, ("batch", "seq", "embed_act"))
     return x, new_cache, aux

@@ -48,6 +48,7 @@ from typing import Any, Dict, List, Optional, Sequence, Union
 import numpy as np
 
 from repro.core.cluster import ClusterSpec
+from repro.obs import serving as obs
 from repro.power.opp import OPPTable
 from repro.power.thermal import ThermalModel, ThermalParams
 from repro.runtime.policy import ScalePolicy, UnitGovernor
@@ -218,41 +219,28 @@ class MultiTenantRuntime:
         """One canonical iteration for every tenant: per-tenant demand →
         weighted-fair arbitration → pool allocation → straggler hedging →
         gated workload step → single pool-level energy charge."""
+        rec = obs.RECORDER
+        with obs.OFF if rec is None else rec.span(
+                "repro.runtime.tick", tick=len(self.pool.t_hist)):
+            return self._tick(dt_s, rec)
+
+    def _tick(self, dt_s: Optional[float], rec: Optional[obs.SpanRecorder]
+              ) -> Dict[str, StepStats]:
         dt = self.dt_s if dt_s is None else dt_s
         t = self._t
         names = list(self._states)
         govs = {m: self._states[m].governor for m in names}
-        desired = {m: govs[m].desired_units(t) for m in names}
-        floors = {m: govs[m]._quantize(govs[m].policy.min_units)
-                  for m in names}
-        weights = {m: self._states[m].tenant.weight for m in names}
-        groups = {m: govs[m].group_units for m in names}
-        grants = weighted_fair_share(desired, floors, weights,
-                                     self.spec.n_units, groups=groups)
-        active = {m: govs[m].apply_target(grants[m], t, dt) for m in names}
-        # straggler hedging (§5.2): a tenant whose oldest queued request
-        # has waited past hedge_after_s borrows one free unit this tick
-        free = self.pool.free_units()
-        hedges: Dict[str, int] = {}
-        for m in names:
-            h = 0
-            deadline = govs[m].policy.hedge_after_s
-            wl = self._states[m].tenant.workload
-            unit_cap = govs[m].unit_cap
-            if deadline is not None and free > 0 \
-                    and (unit_cap is None or active[m] < unit_cap):
-                # a borrowed unit must add real capacity: skip when the
-                # workload's own concurrency cap (e.g. batcher slots)
-                # already binds; a chaos unit_cap (killed units look
-                # free to the pool) gates the borrow the same way
-                cap_fn = getattr(wl, "max_useful_units", None)
-                capped = cap_fn is not None and active[m] + 1 > cap_fn()
-                age = None if capped else _oldest_waiting_s(wl, t)
-                if age is not None and age > deadline:
-                    h = 1
-                    free -= 1
-                    govs[m].hedged += 1
-            hedges[m] = h
+        with obs.OFF if rec is None else rec.span("repro.runtime.gate"):
+            desired = {m: govs[m].desired_units(t) for m in names}
+            floors = {m: govs[m]._quantize(govs[m].policy.min_units)
+                      for m in names}
+            weights = {m: self._states[m].tenant.weight for m in names}
+            groups = {m: govs[m].group_units for m in names}
+            grants = weighted_fair_share(desired, floors, weights,
+                                         self.spec.n_units, groups=groups)
+            active = {m: govs[m].apply_target(grants[m], t, dt)
+                      for m in names}
+            hedges = self._hedges(names, active, t)
         out: Dict[str, StepStats] = {}
         utils: Dict[str, float] = {}
         extras: Dict[str, int] = {}
@@ -276,22 +264,59 @@ class MultiTenantRuntime:
             extras[m] = hedges[m] + over
             utils[m] = s.utilization
             out[m] = s
-        total, p_tenant, powered = self.pool.charge(
-            t, dt, utils, extras,
-            offered=sum(govs[m]._tick_rate for m in names),
-            served=sum(s.work_done for s in out.values()))
-        for m in names:
-            st = self._states[m]
-            out[m].active_units = powered[m]
-            out[m].power_w = p_tenant.get(m, 0.0)
-            out[m].energy_j = self.pool.tenant_energy_j.get(m, 0.0)
-            st.governor.note(t, powered[m], p_tenant.get(m, 0.0),
-                             out[m].utilization, served=out[m].work_done)
-            # drain() is the single delivery channel into Telemetry:
-            # each response reaches a tenant's response log exactly once
-            st.responses.extend(st.tenant.workload.drain())
+            if rec is not None:
+                rec.count("repro.gate" if len(names) == 1
+                          else f"repro.gate/{m}",
+                          rate=govs[m]._tick_rate, desired=desired[m],
+                          granted=grants[m], active=active[m],
+                          hedged=hedges[m], queued=s.queued)
+        with obs.OFF if rec is None else rec.span("repro.runtime.account"):
+            total, p_tenant, powered = self.pool.charge(
+                t, dt, utils, extras,
+                offered=sum(govs[m]._tick_rate for m in names),
+                served=sum(s.work_done for s in out.values()))
+            for m in names:
+                st = self._states[m]
+                out[m].active_units = powered[m]
+                out[m].power_w = p_tenant.get(m, 0.0)
+                out[m].energy_j = self.pool.tenant_energy_j.get(m, 0.0)
+                st.governor.note(t, powered[m], p_tenant.get(m, 0.0),
+                                 out[m].utilization,
+                                 served=out[m].work_done)
+                # drain() is the single delivery channel into Telemetry:
+                # each response reaches a tenant's response log exactly
+                # once
+                st.responses.extend(st.tenant.workload.drain())
         self._t = t + dt
         return out
+
+    def _hedges(self, names: List[str], active: Dict[str, int],
+                t: float) -> Dict[str, int]:
+        """Straggler hedging (§5.2): a tenant whose oldest queued request
+        has waited past hedge_after_s borrows one free unit this tick."""
+        govs = {m: self._states[m].governor for m in names}
+        free = self.pool.free_units()
+        hedges: Dict[str, int] = {}
+        for m in names:
+            h = 0
+            deadline = govs[m].policy.hedge_after_s
+            wl = self._states[m].tenant.workload
+            unit_cap = govs[m].unit_cap
+            if deadline is not None and free > 0 \
+                    and (unit_cap is None or active[m] < unit_cap):
+                # a borrowed unit must add real capacity: skip when the
+                # workload's own concurrency cap (e.g. batcher slots)
+                # already binds; a chaos unit_cap (killed units look
+                # free to the pool) gates the borrow the same way
+                cap_fn = getattr(wl, "max_useful_units", None)
+                capped = cap_fn is not None and active[m] + 1 > cap_fn()
+                age = None if capped else _oldest_waiting_s(wl, t)
+                if age is not None and age > deadline:
+                    h = 1
+                    free -= 1
+                    govs[m].hedged += 1
+            hedges[m] = h
+        return hedges
 
     def tick_all(self, dt_s: Optional[float] = None
                  ) -> Dict[str, StepStats]:

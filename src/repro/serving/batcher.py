@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import model as lm
+from repro.obs import serving as obs
 from repro.serving.engine import ServingEngine
 
 
@@ -27,6 +28,7 @@ class Request:
     max_new_tokens: int
     generated: List[int] = field(default_factory=list)
     done: bool = False
+    submit_ns: Optional[int] = None  # stamped only while spans record
 
 
 class ContinuousBatcher:
@@ -60,8 +62,11 @@ class ContinuousBatcher:
     # ------------------------------------------------------------------
     def submit(self, prompt: np.ndarray, max_new_tokens: int = 16) -> int:
         rid = next(self._rid)
-        self.queue.append(Request(rid, np.asarray(prompt, np.int32),
-                                  max_new_tokens))
+        req = Request(rid, np.asarray(prompt, np.int32), max_new_tokens)
+        rec = obs.RECORDER
+        if rec is not None:
+            req.submit_ns = rec.now_ns()
+        self.queue.append(req)
         return rid
 
     def _ensure_caches(self) -> None:
@@ -69,10 +74,12 @@ class ContinuousBatcher:
             self.caches = lm.init_caches(
                 self.cfg, self.slots, self.engine.max_len)
 
-    def _admit(self, max_slots: Optional[int] = None) -> None:
+    def _admit(self, max_slots: Optional[int] = None) -> int:
+        """Prefill queued requests into free slots; returns how many."""
         limit = self.slots if max_slots is None else min(max_slots,
                                                          self.slots)
         busy = sum(a is not None for a in self.active)
+        admitted = 0
         for slot in range(self.slots):
             if busy >= limit or not self.queue:
                 break
@@ -80,16 +87,22 @@ class ContinuousBatcher:
                 continue
             busy += 1
             req = self.queue.popleft()
-            batch = {"tokens": jnp.asarray(req.prompt[None, :])}
-            logits, cache1 = self.engine.prefill_fn(self.engine.params,
-                                                    batch)
-            self._ensure_caches()
-            self.caches = self._insert_jit(self.caches, cache1, slot)
-            nxt = int(jnp.argmax(logits[0]))
+            rec = obs.RECORDER
+            with obs.OFF if rec is None else rec.span(
+                    "repro.batcher.admit", rid=req.rid, slot=slot,
+                    prompt_len=len(req.prompt),
+                    queued_ns=None if req.submit_ns is None
+                    else rec.now_ns() - req.submit_ns):
+                logits, cache1 = self.engine.prefill(req.prompt)
+                self._ensure_caches()
+                self.caches = self._insert_jit(self.caches, cache1, slot)
+                nxt = int(jnp.argmax(logits[0]))
             req.generated.append(nxt)
             self.active[slot] = req
             self.positions[slot] = len(req.prompt)
             self.tokens[slot] = nxt
+            admitted += 1
+        return admitted
 
     def step(self, max_slots: Optional[int] = None) -> int:
         """One engine tick: admit (up to ``max_slots`` concurrent — the
@@ -97,27 +110,40 @@ class ContinuousBatcher:
         of active slots. Requests already in flight keep decoding even if
         ``max_slots`` drops below the current occupancy; the cap throttles
         admission only."""
-        self._admit(max_slots)
-        live = [s for s in range(self.slots) if self.active[s] is not None]
-        if not live:
-            return 0
-        self._ensure_caches()
-        toks = jnp.asarray(self.tokens[:, None], jnp.int32)
-        pos = jnp.asarray(self.positions, jnp.int32)
-        logits, self.caches = self.engine.decode_fn(
-            self.engine.params, toks, self.caches, pos)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        for s in live:
-            req = self.active[s]
-            req.generated.append(int(nxt[s]))
-            self.positions[s] += 1
-            if len(req.generated) >= req.max_new_tokens:
-                req.done = True
-                self.active[s] = None
-                self.finished.append(req)
-            else:
-                self.tokens[s] = int(nxt[s])
-        return len(live)
+        rec = obs.RECORDER
+        with obs.OFF if rec is None else rec.span(
+                "repro.batcher.step") as args:
+            admitted = self._admit(max_slots)
+            live = [s for s in range(self.slots)
+                    if self.active[s] is not None]
+            if rec is not None:
+                args.update(live=len(live),
+                            positions=int(self.positions[live].sum()),
+                            syncs=admitted + bool(live))
+            if not live:
+                return 0
+            self._ensure_caches()
+            logits, self.caches = self.engine.decode(
+                self.tokens, self.caches, self.positions)
+            with obs.OFF if rec is None else rec.span(
+                    "repro.batcher.sample"):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            with obs.OFF if rec is None else rec.span(
+                    "repro.batcher.update") as upd:
+                before = len(self.finished)
+                for s in live:
+                    req = self.active[s]
+                    req.generated.append(int(nxt[s]))
+                    self.positions[s] += 1
+                    if len(req.generated) >= req.max_new_tokens:
+                        req.done = True
+                        self.active[s] = None
+                        self.finished.append(req)
+                    else:
+                        self.tokens[s] = int(nxt[s])
+                if rec is not None:
+                    upd["finished"] = [r.rid for r in self.finished[before:]]
+            return len(live)
 
     def run_to_completion(self, max_ticks: int = 10000) -> List[Request]:
         start = len(self.finished)

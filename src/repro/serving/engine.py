@@ -12,12 +12,14 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.config import ModelConfig, ServeConfig
 from repro.distributed.sharding import RuleSet, serve_rules, use_sharding
 from repro.kernels import ops
 from repro.kernels.ref import quantize_int8
 from repro.models import model as lm
+from repro.obs import serving as obs
 
 Params = Any
 
@@ -83,6 +85,24 @@ class ServingEngine:
 
     def init_random(self, seed: int = 0) -> None:
         self.load(lm.init_params(self.cfg, jax.random.key(seed)))
+
+    # ------------------------------------------------------------------
+    def prefill(self, prompt: np.ndarray):
+        """Upload one prompt and dispatch the prefill at batch 1; returns
+        (last-position logits, caches)."""
+        rec = obs.RECORDER
+        with obs.OFF if rec is None else rec.span("repro.engine.prefill"):
+            batch = {"tokens": jnp.asarray(prompt[None, :])}
+            return self.prefill_fn(self.params, batch)
+
+    def decode(self, tokens: np.ndarray, caches, positions: np.ndarray):
+        """Upload each slot's last token and position and dispatch one
+        decode step; returns (logits, caches)."""
+        rec = obs.RECORDER
+        with obs.OFF if rec is None else rec.span("repro.engine.decode"):
+            toks = jnp.asarray(tokens[:, None], jnp.int32)
+            pos = jnp.asarray(positions, jnp.int32)
+            return self.decode_fn(self.params, toks, caches, pos)
 
     # ------------------------------------------------------------------
     def generate(self, tokens: jax.Array, max_new_tokens: int,

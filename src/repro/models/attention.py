@@ -4,7 +4,8 @@ Modes:
   * train   — full causal self-attention (no cache)
   * prefill — causal self-attention that also emits the KV cache laid out
               in the decode sharding (``kv_seq`` sequence-sharded)
-  * decode  — one new token appended at ``pos`` against the cache
+  * decode  — one new token written at ``pos`` into the cache stacked over
+              layers, at the layer's index, and attended against
               (flash-decode partial-softmax combine under GSPMD)
 """
 from __future__ import annotations
@@ -86,11 +87,13 @@ def _project_qkv(params: Params, cfg: ModelConfig, x: jax.Array):
 def attn_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
                mode: str, cache: Optional[Params] = None,
                pos: Optional[jax.Array] = None,
-               max_len: Optional[int] = None
+               max_len: Optional[int] = None, layer=None
                ) -> Tuple[jax.Array, Optional[Params]]:
-    """x: (b, s, d). Returns (out, new_cache). Named scopes: ``attn``
-    around it all, ``kv_write`` around the cache writes and ``attn_core``
-    around the attention kernel call."""
+    """x: (b, s, d). Returns (out, new_cache). In decode, ``cache`` is
+    stacked over layers and ``layer`` indexes it: the new position is
+    written at that index in place and the stacked cache is returned.
+    Named scopes: ``attn`` around it all, ``kv_write`` around the cache
+    writes and ``attn_core`` around the attention kernel call."""
     b, s, d = x.shape
     if mode in ("train", "prefill"):
         positions = jnp.arange(s)
@@ -113,11 +116,12 @@ def attn_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
                     "v": shard(vc, ("batch", "kv_seq", "kv_heads_act", None)),
                 }
     else:  # decode
-        assert cache is not None and pos is not None
-        pos_arr = jnp.asarray(pos)
+        assert cache is not None and pos is not None and layer is not None
+        pos_arr = jnp.asarray(pos, jnp.int32)
         per_slot = pos_arr.ndim == 1          # (b,) slot positions
         q, k, v = _project_qkv(params, cfg, x)              # s == 1
         cdt = cache["k"].dtype   # cache may be lower-precision (fp8 lever)
+        layer = jnp.asarray(layer, jnp.int32)
         if per_slot:
             # Per-batch RoPE phases (continuous batching: every slot is at
             # its own sequence position).
@@ -128,30 +132,35 @@ def attn_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
             k = rope_apply(k, sin, cos)
             bidx = jnp.arange(b)
             with jax.named_scope("kv_write"):
-                k_cache = cache["k"].at[bidx, pos_arr].set(
+                k_all = cache["k"].at[layer, bidx, pos_arr].set(
                     k[:, 0].astype(cdt))
-                v_cache = cache["v"].at[bidx, pos_arr].set(
+                v_all = cache["v"].at[layer, bidx, pos_arr].set(
                     v[:, 0].astype(cdt))
-            length = pos_arr.astype(jnp.int32) + 1
+            length = pos_arr + 1
         else:
-            positions = pos_arr.reshape(1)
-            sin, cos = rope_table(positions, cfg.resolved_head_dim,
+            sin, cos = rope_table(pos_arr.reshape(1), cfg.resolved_head_dim,
                                   cfg.rope_theta)
             q = rope_apply(q, sin, cos)
             k = rope_apply(k, sin, cos)
+            zero = jnp.zeros((), jnp.int32)
+            at = (layer, zero, pos_arr, zero, zero)
             with jax.named_scope("kv_write"):
-                k_cache = jax.lax.dynamic_update_slice_in_dim(
-                    cache["k"], k.astype(cdt), pos, axis=1)
-                v_cache = jax.lax.dynamic_update_slice_in_dim(
-                    cache["v"], v.astype(cdt), pos, axis=1)
+                k_all = jax.lax.dynamic_update_slice(
+                    cache["k"], k[None].astype(cdt), at)
+                v_all = jax.lax.dynamic_update_slice(
+                    cache["v"], v[None].astype(cdt), at)
             length = jnp.full((b,), pos_arr + 1, jnp.int32)
-        k_cache = shard(k_cache, ("batch", "kv_seq", "kv_heads_act", None))
-        v_cache = shard(v_cache, ("batch", "kv_seq", "kv_heads_act", None))
+        stacked = (None, "batch", "kv_seq", "kv_heads_act", None)
+        k_all, v_all = shard(k_all, stacked), shard(v_all, stacked)
+        k_cache, v_cache = (
+            shard(jax.lax.dynamic_index_in_dim(t, layer, keepdims=False),
+                  stacked[1:])
+            for t in (k_all, v_all))
         with jax.named_scope("attn_core"):
             out1 = ops.decode_attention(q[:, 0], k_cache, v_cache, length,
                                         impl=cfg.attn_impl)
         out = out1[:, None]
-        new_cache = {"k": k_cache, "v": v_cache}
+        new_cache = {"k": k_all, "v": v_all}
     out = shard(out, ("batch", "seq", "heads_act", None))
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
     return y, new_cache

@@ -111,9 +111,11 @@ def _gated_norm(y: jax.Array, z: jax.Array, scale: jax.Array,
 
 
 def mamba_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
-                mode: str, cache: Optional[Params] = None
+                mode: str, cache: Optional[Params] = None, layer=None
                 ) -> Tuple[jax.Array, Optional[Params]]:
-    """x: (b, s, d) -> (out, new_cache)."""
+    """x: (b, s, d) -> (out, new_cache). In decode, ``cache`` is stacked
+    over layers and ``layer`` indexes it; the state is small, so it is
+    read at that index and written back there whole."""
     m, di, nh = _dims(cfg)
     n, p = m.d_state, m.headdim
     b, s, d = x.shape
@@ -141,8 +143,11 @@ def mamba_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
                 "ssd": state,
             }
     else:  # decode: s == 1
-        assert cache is not None
-        conv_hist = jnp.concatenate([cache["conv"], xbc], axis=1)
+        assert cache is not None and layer is not None
+        cur = jax.tree.map(
+            lambda t: jax.lax.dynamic_index_in_dim(t, layer, keepdims=False),
+            cache)
+        conv_hist = jnp.concatenate([cur["conv"], xbc], axis=1)
         w, bias = params["conv_w"], params["conv_b"]
         acc = jnp.einsum("bkc,kc->bc", conv_hist.astype(jnp.float32),
                          w.astype(jnp.float32))
@@ -153,9 +158,12 @@ def mamba_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
         dt = jax.nn.softplus(dt_raw[:, 0].astype(jnp.float32)
                              + params["dt_bias"][None])
         y1, state = ssd_decode_ref(xs, dt, A, B, C, params["D"],
-                                   cache["ssd"])
+                                   cur["ssd"])
         y = y1.reshape(b, 1, di)
-        new_cache = {"conv": conv_hist[:, 1:], "ssd": state}
+        new_cache = jax.tree.map(
+            lambda t, u: jax.lax.dynamic_update_index_in_dim(
+                t, u.astype(t.dtype), layer, 0),
+            cache, {"conv": conv_hist[:, 1:], "ssd": state})
 
     y = _gated_norm(y, z, params["norm_scale"], cfg.norm_eps)
     y = shard(y, ("batch", "seq", "mlp_act"))

@@ -81,8 +81,11 @@ def layer_specs(cfg: ModelConfig, i: int) -> Params:
 
 def layer_apply(params: Params, cfg: ModelConfig, i_sig: Tuple[str, str],
                 x: jax.Array, *, mode: str, cache: Optional[Params],
-                pos, max_len: Optional[int] = None
+                pos, max_len: Optional[int] = None, layer=None
                 ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
+    """In decode mode ``cache`` is stacked over the block repeats and
+    ``layer`` is this layer's index into it; the stacked cache comes back
+    with this layer's entry updated."""
     kind, ffn = i_sig
     with jax.named_scope("norm"):
         h = rmsnorm_apply(params["norm1"], x, cfg.norm_eps,
@@ -90,10 +93,10 @@ def layer_apply(params: Params, cfg: ModelConfig, i_sig: Tuple[str, str],
     if kind == ATTN:
         mix, new_cache = attn_mod.attn_apply(
             params["mixer"], cfg, h, mode=mode, cache=cache, pos=pos,
-            max_len=max_len)
+            max_len=max_len, layer=layer)
     else:
         mix, new_cache = mamba_mod.mamba_apply(
-            params["mixer"], cfg, h, mode=mode, cache=cache)
+            params["mixer"], cfg, h, mode=mode, cache=cache, layer=layer)
     x = x + mix
     aux = jnp.zeros((), jnp.float32)
     if ffn != "none":
@@ -184,19 +187,25 @@ def stack_apply(blocks: List[Params], cfg: ModelConfig, x: jax.Array, *,
                 pos=None, scan: bool = True, remat: str = "none",
                 max_len: Optional[int] = None
                 ) -> Tuple[jax.Array, Optional[List[Params]], jax.Array]:
-    """Run all layers. Returns (x, new_caches, aux_loss_sum)."""
+    """Run all layers. Returns (x, new_caches, aux_loss_sum).
+
+    Decode carries the stacked caches through the layers with the repeat
+    index: each layer writes its new entry into them at that index, in
+    place, and the same stacked buffers come back. Prefill returns each
+    layer's cache as the scan's output, stacked."""
     p = block_period(cfg)
     nb = cfg.num_layers // p
     sigs = [layer_signature(cfg, j) for j in range(p)]
+    decode = mode == "decode"
 
-    def block_fn(x, block_params, block_caches, pos):
+    def block_fn(x, block_params, block_caches, r):
         new_caches = []
         aux_total = jnp.zeros((), jnp.float32)
         for j in range(p):
             cache_j = None if block_caches is None else block_caches[j]
             x, nc, aux = layer_apply(
-                block_params[j], cfg, sigs[j], x,
-                mode=mode, cache=cache_j, pos=pos, max_len=max_len)
+                block_params[j], cfg, sigs[j], x, mode=mode, cache=cache_j,
+                pos=pos, max_len=max_len, layer=r)
             new_caches.append(nc)
             aux_total = aux_total + aux
         return x, new_caches, aux_total
@@ -209,36 +218,43 @@ def stack_apply(blocks: List[Params], cfg: ModelConfig, x: jax.Array, *,
             block_fn,
             policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
 
-    needs_cache = mode in ("prefill", "decode")
+    zero = jnp.zeros((), jnp.float32)
     if scan and nb > 1:
-        def body(carry, xs):
+        if decode:
+            def body(carry, xs):
+                x, aux, cs = carry
+                bp, r = xs
+                x, cs, a = fn(x, bp, cs, r)
+                return (x, aux + a, cs), None
+
+            (x, aux, caches), _ = jax.lax.scan(
+                body, (x, zero, caches),
+                (blocks, jnp.arange(nb, dtype=jnp.int32)))
+            return x, caches, aux
+
+        def body(carry, bp):
             x, aux = carry
-            bp, bc = xs
-            x, ncs, a = fn(x, bp, bc, pos)
+            x, ncs, a = fn(x, bp, None, None)
             return (x, aux + a), ncs
 
-        xs = (blocks, caches if caches is not None else [None] * p)
-        (x, aux), new_caches = jax.lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), xs)
-        out_caches = new_caches if needs_cache else None
-        return x, out_caches, aux
-    else:
-        # Unrolled path: index the stacked leaves per repeat.
-        aux = jnp.zeros((), jnp.float32)
-        new_stack = [[] for _ in range(p)] if needs_cache else None
-        for r in range(nb):
-            bp = jax.tree.map(lambda a: a[r], blocks)
-            bc = (None if caches is None
-                  else jax.tree.map(lambda a: a[r], caches))
-            x, ncs, a = fn(x, bp, bc, pos)
-            aux = aux + a
-            if needs_cache:
-                for j in range(p):
-                    new_stack[j].append(ncs[j])
-        out_caches = None
-        if needs_cache:
-            out_caches = [
-                jax.tree.map(lambda *xs: jnp.stack(xs), *new_stack[j])
-                for j in range(p)
-            ]
-        return x, out_caches, aux
+        (x, aux), new_caches = jax.lax.scan(body, (x, zero), blocks)
+        return x, (new_caches if mode == "prefill" else None), aux
+
+    # Unrolled path: index the stacked leaves per repeat.
+    aux = zero
+    new_stack = [[] for _ in range(p)]
+    for r in range(nb):
+        bp = jax.tree.map(lambda a: a[r], blocks)
+        x, ncs, a = fn(x, bp, caches, r)
+        aux = aux + a
+        if decode:
+            caches = ncs
+        else:
+            for j, c in enumerate(ncs):
+                new_stack[j].append(c)
+    if decode:
+        return x, caches, aux
+    if mode == "prefill":
+        return x, [jax.tree.map(lambda *xs: jnp.stack(xs), *new_stack[j])
+                   for j in range(p)], aux
+    return x, None, aux

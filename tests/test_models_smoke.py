@@ -48,12 +48,40 @@ def test_smoke_grad_step_updates_params(arch):
     assert np.isfinite(gnorm) and gnorm > 0, arch
 
 
-@pytest.mark.parametrize("arch", ["internlm2-1.8b", "granite-moe-1b-a400m",
-                                  "mamba2-130m", "jamba-1.5-large-398b",
-                                  "musicgen-large", "internvl2-1b"])
-def test_decode_matches_forward_fp32(arch):
-    """prefill(s) + decode(1) must equal the full forward at position s."""
+def _decode_cases():
+    """The original cases (scanned layers, one position for the batch)
+    keep their ids; a dense, a MoE and a hybrid attention + Mamba2 stack
+    add the unrolled layers and slots at different positions. The hybrid
+    stack takes 8 layers so that its period of 4 repeats in the scan."""
+    cases = [pytest.param(a, None, True, False, id=a)
+             for a in ["internlm2-1.8b", "granite-moe-1b-a400m",
+                       "mamba2-130m", "jamba-1.5-large-398b",
+                       "musicgen-large", "internvl2-1b"]]
+    for arch, layers in [("internlm2-1.8b", None),
+                         ("granite-moe-1b-a400m", None),
+                         ("jamba-1.5-large-398b", 8)]:
+        for scan in (True, False):
+            for per_slot in (False, True):
+                if scan and not per_slot and layers is None:
+                    continue
+                tag = "-".join(
+                    [arch] + ([f"{layers}l"] if layers else [])
+                    + ["scan" if scan else "unrolled"]
+                    + (["per_slot"] if per_slot else []))
+                cases.append(pytest.param(arch, layers, scan, per_slot,
+                                          id=tag))
+    return cases
+
+
+@pytest.mark.parametrize("arch,layers,scan,per_slot", _decode_cases())
+def test_decode_matches_forward_fp32(arch, layers, scan, per_slot):
+    """prefill + decode(1) must equal the full forward at the decoded
+    position, and the caches decode returns must equal those a prefill of
+    one more token builds, at every position of every layer."""
     cfg = smoke_config(get_config(arch)).replace(dtype="float32")
+    if layers:
+        cfg = cfg.replace(num_layers=layers, layer_pattern=tuple(
+            cfg.layer_pattern) * (layers // cfg.num_layers))
     if cfg.moe is not None:
         # capacity dropping legitimately depends on sequence length; use a
         # drop-free capacity so the equivalence is exact.
@@ -62,6 +90,7 @@ def test_decode_matches_forward_fp32(arch):
                                                   capacity_factor=16.0))
     params = lm.init_params(cfg, jax.random.key(1))
     b, s = 2, 16
+    lens = [s, s - 5] if per_slot else [s, s]
     toks = jax.random.randint(jax.random.key(2), (b, s + 1), 0,
                               cfg.vocab_size)
     batch = {"tokens": toks}
@@ -71,20 +100,52 @@ def test_decode_matches_forward_fp32(arch):
         ve = jnp.asarray(np.random.default_rng(0).standard_normal(
             (b, ft, cfg.frontend_dim or cfg.d_model)), jnp.float32)
         batch["vision_embeds"] = ve
-    full, _, _ = lm.forward(params, cfg, batch, mode="train")
-    pre_batch = {"tokens": toks[:, :s]}
-    if ve is not None:
-        pre_batch["vision_embeds"] = ve
-    lg_pre, caches = lm.prefill(params, cfg, pre_batch,
-                                max_len=s + ft + 8)
-    lg_dec, _ = lm.decode_step(params, cfg, toks[:, s:s + 1], caches,
-                               pos=s + ft)
+    full, _, _ = jax.jit(lambda p, bt: lm.forward(
+        p, cfg, bt, mode="train", scan=scan))(params, batch)
+    max_len = s + ft + 8
+    prefill = jax.jit(lambda p, bt: lm.prefill(p, cfg, bt, max_len=max_len,
+                                               scan=scan))
+
+    def prefill_slots(extra):
+        """The batch prefilled on its first s + extra tokens in one call;
+        with per-slot positions, each slot prefilled alone on its first
+        lens[i] + extra tokens and the caches joined along the batch."""
+        if not per_slot:
+            pre = {"tokens": toks[:, :s + extra]}
+            if ve is not None:
+                pre["vision_embeds"] = ve
+            return prefill(params, pre)
+        logits, caches = [], []
+        for i, n in enumerate(lens):
+            pre = {"tokens": toks[i:i + 1, :n + extra]}
+            if ve is not None:
+                pre["vision_embeds"] = ve[i:i + 1]
+            lg, c = prefill(params, pre)
+            logits.append(lg)
+            caches.append(c)
+        return (jnp.concatenate(logits),
+                jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=1),
+                             *caches))
+
+    lg_pre, caches = prefill_slots(0)
+    last = jnp.stack([toks[i, n] for i, n in enumerate(lens)])[:, None]
+    pos = jnp.asarray(lens, jnp.int32) + ft if per_slot else s + ft
+    lg_dec, dec_caches = jax.jit(lambda p, t, c, ps: lm.decode_step(
+        p, cfg, t, c, ps, scan=scan))(params, last, caches, pos)
+    rows = np.arange(b)
+    at = np.asarray(lens)
     np.testing.assert_allclose(np.asarray(lg_pre),
-                               np.asarray(full[:, s - 1 + ft]),
+                               np.asarray(full[rows, at - 1 + ft]),
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(lg_dec),
-                               np.asarray(full[:, s + ft]),
+                               np.asarray(full[rows, at + ft]),
                                rtol=1e-4, atol=1e-4)
+    _, want = prefill_slots(1)
+    assert jax.tree.structure(dec_caches) == jax.tree.structure(want)
+    for got, ref in zip(jax.tree.leaves(dec_caches), jax.tree.leaves(want)):
+        assert got.shape == ref.shape and got.dtype == ref.dtype
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-4, atol=1e-4)
 
 
 def test_scan_equals_unrolled():

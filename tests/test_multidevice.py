@@ -133,3 +133,55 @@ print("OK")
 """
     r = subproc(code, devices=4)
     assert "OK" in r.stdout, r.stderr
+
+
+@pytest.mark.parametrize("serve_fsdp", [False, True])
+def test_sharded_decode_step_matches_one_device(subproc, serve_fsdp):
+    """The decode step on a 2x2 mesh under the serving rules (batch over
+    data, the caches' kv_seq over model) gives one device's logits and
+    caches, for slots at different positions and for one shared position.
+    The stacked caches carried through the layers keep their sharding,
+    and no collective moves as much as one layer's shard of the cache."""
+    code = f"""
+import re
+import jax, jax.numpy as jnp, numpy as np
+from repro.config import ServeConfig, get_config, smoke_config
+from repro.launch.mesh import make_mesh
+from repro.serving.engine import ServingEngine
+cfg = smoke_config(get_config("internlm2-1.8b")).replace(dtype="float32")
+scfg = ServeConfig(max_seq_len=32, serve_fsdp={serve_fsdp})
+mesh = make_mesh((2, 2), ("data", "model"))
+one, sharded = ServingEngine(cfg, scfg), ServingEngine(cfg, scfg, mesh=mesh)
+for e in (one, sharded):
+    e.init_random(0)
+toks = jax.random.randint(jax.random.key(1), (2, 12), 0, cfg.vocab_size)
+nxt = toks[:, -1:]
+for pos in (jnp.asarray([12, 9], jnp.int32), 12):
+    def run(e):
+        _, caches = e.prefill_fn(e.params, {{"tokens": toks}})
+        return caches, e.decode_fn(e.params, nxt, caches, pos)
+    _, (lg1, c1) = run(one)
+    before, (lg4, c4) = run(sharded)
+    np.testing.assert_allclose(np.asarray(lg4), np.asarray(lg1),
+                               rtol=1e-5, atol=1e-5)
+    for a, b in zip(jax.tree.leaves(c4), jax.tree.leaves(c1)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+    specs = [c["k"].sharding.spec for c in c4]
+    assert all(s[2] == "model" for s in specs), specs
+    assert specs == [c["k"].sharding.spec for c in before], specs
+k = c4[0]["k"]
+layer_shard = int(np.prod(k.sharding.shard_shape(k.shape)[1:]))
+_, caches = sharded.prefill_fn(sharded.params, {{"tokens": toks}})
+text = sharded.decode_fn.lower(sharded.params, nxt, caches,
+                               jnp.asarray([12, 9], jnp.int32)
+                               ).compile().as_text()
+moved = [int(np.prod([int(d) for d in m.group(1).split(",") if d]))
+         for m in re.finditer(
+             r"= \\w+\\[([\\d,]*)\\]\\S* (?:all-gather|all-to-all|"
+             r"collective-permute|all-reduce)\\(", text)]
+assert moved and max(moved) < layer_shard, (moved, layer_shard)
+print("OK", layer_shard, sorted(moved))
+"""
+    r = subproc(code, devices=4)
+    assert "OK" in r.stdout, r.stderr

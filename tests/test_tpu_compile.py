@@ -3,8 +3,9 @@ attached, at internlm2-1.8b widths (the SSD scan at mamba2-130m widths).
 
 Nothing runs: each test lowers and compiles for one chip of a ``v5e:2x2``
 topology and asserts that the Pallas kernel is in the program
-(``tpu_custom_call``). The topology and every sharding are built inside
-the fixtures below, never at import time, so that under several pytest
+(``tpu_custom_call``); the decode step also reads the compiler's memory
+analysis. The topology and every sharding are built inside the fixtures
+below, never at import time, so that under several pytest
 workers only the worker given this file loads the TPU compiler.
 """
 import dataclasses
@@ -118,18 +119,28 @@ def _placed(tree, one_chip):
 
 
 def test_decode_step_compiles(tpu_branch, one_chip, spec):
+    """The decode step with its caches donated, as the engine jits it:
+    the Pallas kernel is in the program, the whole cache aliases the
+    output, and no second copy of it is made (temp below a quarter of the
+    cache)."""
     cfg = get_config(ARCH)
     max_len = ops.cache_len(342 + 32 + 8)
     params = _placed(lm.param_shapes(cfg), one_chip)
     caches = _placed(jax.eval_shape(lambda: lm.init_caches(cfg, 4, max_len)),
                      one_chip)
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(caches))
 
     def step(params, tokens, caches, pos):
         return lm.decode_step(params, cfg, tokens, caches, pos)
 
-    text = _compiled_text(step, params, spec((4, 1), jnp.int32), caches,
-                          spec((4,), jnp.int32))
-    assert "tpu_custom_call" in text
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        params, spec((4, 1), jnp.int32), caches,
+        spec((4,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes / 4
 
 
 def test_prefill_compiles_at_unaligned_length(tpu_branch, one_chip, spec):

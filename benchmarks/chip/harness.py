@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import dataclasses
 import gc
+import importlib
 import json
 import math
 import os
@@ -72,25 +73,10 @@ def seed32(seed: int) -> int:
 # ---------------------------------------------------------------------------
 # The server under test.
 # ---------------------------------------------------------------------------
-def model_config(conf: dict):
-    """The registry's config, set to the file's published values, and
-    checked against its widths."""
-    from repro.config import get_config
-
-    hf = conf["hf"]
-    cfg = get_config(conf["registry"]).replace(
-        rope_theta=float(hf["rope_theta"]),
-        norm_eps=float(hf["rms_norm_eps"]))
-    dims = work.Dims.from_hf(hf)
-    got = work.Dims(
-        layers=cfg.num_layers, d=cfg.d_model, heads=cfg.num_heads,
-        kv_heads=cfg.num_kv_heads, head_dim=cfg.resolved_head_dim,
-        d_ff=cfg.d_ff, vocab=cfg.vocab_size)
-    if (got != dims or cfg.moe is not None
-            or cfg.tie_embeddings != bool(hf["tie_word_embeddings"])):
-        raise ValueError(f"{conf['registry']} runs {got}, the file "
-                         f"states {dims}")
-    return cfg
+def family(conf: dict):
+    """``families/<family>.py``, the module the configuration names: all
+    that the harness knows of the model's shape."""
+    return importlib.import_module(f"families.{conf['family']}")
 
 
 class Server:
@@ -335,11 +321,8 @@ def sample_for_check(reqs: list, check: dict, seed: int) -> list:
 
 def check_outputs(conf: dict, mix: dict, seed: int, sample: list) -> dict:
     """Gaps of the served tokens below the reference's best logit."""
-    import reference
-
-    gaps = reference.gaps(
-        reference.Spec.from_hf(conf["hf"]), seed32(seed),
-        [r.prompt for r in sample],
+    gaps = family(conf).gaps(
+        conf, seed32(seed), [r.prompt for r in sample],
         [list(r.handle.generated) for r in sample],
         traffic_mod.max_output(mix))
     flat = np.concatenate(gaps) if gaps else np.zeros(0)
@@ -384,7 +367,8 @@ def run(c: dict, seed: int, seconds: float, trace: bool, t_start: float,
     use_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     conf, mix = c["config"], c["mix"]
-    cfg = model_config(conf)
+    fam = family(conf)
+    cfg = fam.program_config(conf)
     dev = device_info(jax)
     peak = work.peaks(peak_kind or dev["kind"])
     counter = CompileCounter()
@@ -400,7 +384,7 @@ def run(c: dict, seed: int, seconds: float, trace: bool, t_start: float,
     lead = float(mix["lead_in_s"])
     horizon = lead + seconds + 60.0
     reqs = traffic_mod.make_requests(
-        mix, seed, conf["hf"]["vocab_size"],
+        mix, seed, fam.vocab(conf),
         traffic_mod.request_count(mix, horizon))
     setup_s = time.time() - t_start + lead
     w = drive(srv, mix, reqs, seconds, trace_s=mix["trace_s"] if trace
@@ -411,7 +395,7 @@ def run(c: dict, seed: int, seconds: float, trace: bool, t_start: float,
     per_layer, breakdown = {}, None
     if trace:
         import readers
-        ctx = readers.Context.build(srv, w, conf, peak)
+        ctx = readers.Context.build(srv, w, fam.dims(conf), peak)
         per_layer = readers.read_all(c["per_layer"], ctx)
         dev["busy_s"] = ctx.busy_s
         dev["window_s"] = ctx.window_s

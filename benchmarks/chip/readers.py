@@ -10,10 +10,9 @@ from __future__ import annotations
 
 import importlib.util
 from dataclasses import dataclass
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 import devtrace
-import work
 from harness import HERE
 
 
@@ -28,11 +27,11 @@ class Context:
     reqs: list
     ticks: List[tuple]         # (t0, t1, active_units, queued)
     slots: int
-    dims: work.Dims
+    dims: Any                  # the family's dims(conf)
     peak: dict
 
     @classmethod
-    def build(cls, srv, w, conf: dict, peak: dict) -> "Context":
+    def build(cls, srv, w, dims: Any, peak: dict) -> "Context":
         events = devtrace.load(w.trace_dir)
         lo, hi = devtrace.window(events)
         return cls(
@@ -41,7 +40,7 @@ class Context:
             spans=[s for s in srv.spans
                    if s[1] >= w.trace_lo and s[2] <= w.trace_hi],
             reqs=w.reqs, ticks=w.ticks, slots=srv.slots,
-            dims=work.Dims.from_hf(conf["hf"]), peak=peak)
+            dims=dims, peak=peak)
 
     # -- shared helpers ------------------------------------------------------
     @property

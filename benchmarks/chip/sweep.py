@@ -45,7 +45,8 @@ def main() -> int:
         return 3
     use_compile_cache()
     conf, mix = c["config"], c["mix"]
-    srv = harness.Server(harness.model_config(conf), mix, args.seed,
+    fam = harness.family(conf)
+    srv = harness.Server(fam.program_config(conf), mix, args.seed,
                          conf.get("serve", {}))
     srv.warm(mix["prompt"]["grid"])
     harness.log(f"set-up {time.time() - T_START:.1f} s")
@@ -53,7 +54,7 @@ def main() -> int:
         m = dict(mix, arrivals={"kind": "poisson", "rate_per_s": rate})
         srv.runtime.governor.unit_rate = rate / srv.slots
         reqs = traffic.make_requests(
-            m, args.seed, conf["hf"]["vocab_size"],
+            m, args.seed, fam.vocab(conf),
             traffic.request_count(m, m["lead_in_s"] + args.seconds))
         w = harness.drive(srv, m, reqs, args.seconds, drain_s=0.0)
         inside = [t for t in w.ticks if w.lo <= t[0] < w.hi]

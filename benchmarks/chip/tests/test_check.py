@@ -57,8 +57,10 @@ def cell():
 def run(cell, patch=None, monkeypatch=None, seed=SEED, serve=None):
     c, sm = cell
     hf = c["config"]["hf"]
-    monkeypatch.setattr(harness, "model_config", lambda conf: sm.replace(
-        rope_theta=float(hf["rope_theta"]), norm_eps=float(hf["rms_norm_eps"])))
+    monkeypatch.setattr(
+        harness.family(c["config"]), "program_config",
+        lambda conf: sm.replace(rope_theta=float(hf["rope_theta"]),
+                                norm_eps=float(hf["rms_norm_eps"])))
     return harness.run(c, seed, 3.0, False, time.time(), patch=patch,
                        peak_kind="TPU v5 lite", serve=serve)
 

@@ -35,9 +35,26 @@ class MoEConfig:
     # "v2" (drop-mode scatter into an expert-flat buffer that shards
     # cleanly over the model axis — the EP-collective hillclimb lever)
     dispatch: str = "v1"
+    # width of a shared expert that every token passes through (0: none)
+    d_ff_shared: int = 0
+    # the chip's share of the experts: experts [expert_first, expert_first
+    # + experts_held) live here (0 held: all of them). The router keeps
+    # all num_experts outputs; the layer computes its held experts' part.
+    expert_first: int = 0
+    experts_held: int = 0
 
     def is_moe_layer(self, layer_idx: int) -> bool:
         return layer_idx % self.period == self.offset
+
+    @property
+    def held(self) -> int:
+        """How many experts this chip holds."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def cut(self) -> bool:
+        """Whether this chip holds only a share of the experts."""
+        return self.held < self.num_experts
 
 
 @dataclass(frozen=True)
@@ -96,6 +113,17 @@ class ModelConfig:
     # input_specs()).
     frontend_tokens: int = 0
     frontend_dim: int = 0               # dim of precomputed frontend embeds
+    # attention without positions (NoPE): no rotary embedding anywhere
+    rope: bool = True
+    # Granite's factors; None adds no operation. The embeddings are
+    # multiplied by ``embedding_multiplier``, each residual branch by
+    # ``residual_multiplier``, the attention scores by
+    # ``attention_multiplier`` (in place of 1/sqrt(head_dim)), and the
+    # logits are divided by ``logits_scaling``.
+    embedding_multiplier: Optional[float] = None
+    residual_multiplier: Optional[float] = None
+    attention_multiplier: Optional[float] = None
+    logits_scaling: Optional[float] = None
     dtype: str = "bfloat16"
     # Notes carried into DESIGN/EXPERIMENTS.
     source: str = ""
@@ -165,8 +193,10 @@ class ModelConfig:
             if self.is_moe_layer(i):
                 assert self.moe is not None
                 e_p = 3 * d * self.moe.d_ff_expert
-                lt += self.moe.num_experts * e_p + d * self.moe.num_experts
-                la += self.moe.top_k * e_p + d * self.moe.num_experts
+                s_p = 3 * d * self.moe.d_ff_shared
+                lt += (self.moe.held * e_p + s_p
+                       + d * self.moe.num_experts)
+                la += self.moe.top_k * e_p + s_p + d * self.moe.num_experts
             elif self.d_ff:
                 lt += dense_ffn_p
                 la += dense_ffn_p
@@ -327,10 +357,19 @@ def smoke_config(cfg: ModelConfig) -> ModelConfig:
         n_kv = n_heads  # preserve MHA-ness (musicgen)
     moe = None
     if cfg.moe is not None:
+        # a cut share stays cut: the same fraction of 4 experts, at least
+        # one, from the scaled first index
+        e = 4
+        held = max(1, e * cfg.moe.held // cfg.moe.num_experts)
+        first = min(e * cfg.moe.expert_first // cfg.moe.num_experts,
+                    e - held)
         moe = MoEConfig(
-            num_experts=4, top_k=min(cfg.moe.top_k, 2), d_ff_expert=32,
+            num_experts=e, top_k=min(cfg.moe.top_k, 2), d_ff_expert=32,
             period=cfg.moe.period, offset=cfg.moe.offset,
             capacity_factor=cfg.moe.capacity_factor,
+            d_ff_shared=48 if cfg.moe.d_ff_shared else 0,
+            expert_first=first if cfg.moe.cut else 0,
+            experts_held=held if cfg.moe.cut else 0,
         )
     mamba = None
     if cfg.mamba is not None:

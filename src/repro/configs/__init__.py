@@ -11,6 +11,7 @@ from repro.configs import (  # noqa: F401
     internvl2_1b,
     jamba_1p5_large_398b,
     bert_base,
+    granite_4_0_h_small,
 )
 
 ASSIGNED_ARCHS = (

@@ -17,8 +17,9 @@ Two cases stay on the jnp paths on the TPU too:
   their layouts (``impl="chunked_kvrep"`` included). On a single chip,
   ``impl`` and ``chunk`` do nothing.
 
-Prefill and the SSD scan take any sequence length: the TPU branch pads
-to the kernel's tile and slices the result back.
+Prefill and the SSD scan take any sequence length: prefill's TPU branch
+pads to the kernel's tile, the SSD scan pads to its chunk on every
+backend, and both slice the result back.
 
 A ``pallas_call`` has a JVP but no transpose rule, so every kernel here
 is differentiated as its oracle: the backward pass recomputes the
@@ -155,9 +156,7 @@ def int8_matmul(x_q, sx, w_q, sw, out_dtype=jnp.float32):
 
 
 def ssd(x, dt, A, B, C, D, *, chunk: int = 128):
-    """Returns (y, final_state (b,h,p,n) fp32)."""
-    if not on_tpu():
-        return _ref.ssd_chunked(x, dt, A, B, C, D, chunk=chunk)
+    """Returns (y, final_state (b,h,p,n) fp32), at any sequence length."""
     # Padded steps have dt = 0: no decay and no input, so the real
     # outputs and the final state are those of the unpadded sequence.
     s = x.shape[1]
@@ -166,9 +165,13 @@ def ssd(x, dt, A, B, C, D, *, chunk: int = 128):
         x, dt, B, C = (
             jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
             for t in (x, dt, B, C))
-    y, state = _grad_by_ref(functools.partial(_pl_ssd, chunk=chunk),
-                            functools.partial(_ref.ssd_chunked, chunk=chunk),
-                            x, dt, A, B, C, D)
+    if on_tpu():
+        y, state = _grad_by_ref(
+            functools.partial(_pl_ssd, chunk=chunk),
+            functools.partial(_ref.ssd_chunked, chunk=chunk),
+            x, dt, A, B, C, D)
+    else:
+        y, state = _ref.ssd_chunked(x, dt, A, B, C, D, chunk=chunk)
     return y[:, :s], state
 
 

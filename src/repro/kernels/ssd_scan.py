@@ -24,6 +24,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# the kernel's name in the compiled program and the device trace
+KERNEL_NAME = "ssd_scan"
 
 
 def _ssd_kernel(a_coef_ref, x_ref, dt_ref, dt_row_ref, b_ref, c_ref, y_ref,
@@ -122,6 +124,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     y, state = pl.pallas_call(
         functools.partial(_ssd_kernel, chunk=chunk),
         grid_spec=grid_spec,
+        name=KERNEL_NAME,
         out_shape=[
             jax.ShapeDtypeStruct((b * h, s, p), x.dtype),
             jax.ShapeDtypeStruct((b * h, n, p), jnp.float32),

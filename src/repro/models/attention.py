@@ -1,4 +1,5 @@
-"""GQA multi-head attention with RoPE and a decode KV cache.
+"""GQA multi-head attention with RoPE (or no positions) and a decode KV
+cache.
 
 Modes:
   * train   — full causal self-attention (no cache)
@@ -7,6 +8,10 @@ Modes:
   * decode  — one new token written at ``pos`` into the cache stacked over
               layers, at the layer's index, and attended against
               (flash-decode partial-softmax combine under GSPMD)
+
+With ``cfg.rope`` off (NoPE) no rotary embedding is applied in any mode;
+``cfg.attention_multiplier``, where set, scales the scores in place of
+``1/sqrt(head_dim)``.
 """
 from __future__ import annotations
 
@@ -96,14 +101,17 @@ def attn_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
     writes and ``attn_core`` around the attention kernel call."""
     b, s, d = x.shape
     if mode in ("train", "prefill"):
-        positions = jnp.arange(s)
-        sin, cos = rope_table(positions, cfg.resolved_head_dim, cfg.rope_theta)
+        if cfg.rope:
+            sin, cos = rope_table(jnp.arange(s), cfg.resolved_head_dim,
+                                  cfg.rope_theta)
         q, k, v = _project_qkv(params, cfg, x)
-        q = rope_apply(q, sin, cos)
-        k = rope_apply(k, sin, cos)
+        if cfg.rope:
+            q = rope_apply(q, sin, cos)
+            k = rope_apply(k, sin, cos)
         with jax.named_scope("attn_core"):
-            out = ops.attention(q, k, v, causal=True, impl=cfg.attn_impl,
-                                chunk=cfg.attn_chunk)
+            out = ops.attention(q, k, v, causal=True,
+                                scale=cfg.attention_multiplier,
+                                impl=cfg.attn_impl, chunk=cfg.attn_chunk)
         new_cache = None
         if mode == "prefill":
             with jax.named_scope("kv_write"):
@@ -123,13 +131,14 @@ def attn_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
         cdt = cache["k"].dtype   # cache may be lower-precision (fp8 lever)
         layer = jnp.asarray(layer, jnp.int32)
         if per_slot:
-            # Per-batch RoPE phases (continuous batching: every slot is at
-            # its own sequence position).
-            sin, cos = rope_table(pos_arr, cfg.resolved_head_dim,
-                                  cfg.rope_theta)           # (b, d/2)
-            sin, cos = sin[:, None], cos[:, None]           # (b, 1, d/2)
-            q = rope_apply(q, sin, cos)
-            k = rope_apply(k, sin, cos)
+            if cfg.rope:
+                # Per-batch RoPE phases (continuous batching: every slot
+                # is at its own sequence position).
+                sin, cos = rope_table(pos_arr, cfg.resolved_head_dim,
+                                      cfg.rope_theta)       # (b, d/2)
+                sin, cos = sin[:, None], cos[:, None]       # (b, 1, d/2)
+                q = rope_apply(q, sin, cos)
+                k = rope_apply(k, sin, cos)
             bidx = jnp.arange(b)
             with jax.named_scope("kv_write"):
                 k_all = cache["k"].at[layer, bidx, pos_arr].set(
@@ -138,10 +147,11 @@ def attn_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
                     v[:, 0].astype(cdt))
             length = pos_arr + 1
         else:
-            sin, cos = rope_table(pos_arr.reshape(1), cfg.resolved_head_dim,
-                                  cfg.rope_theta)
-            q = rope_apply(q, sin, cos)
-            k = rope_apply(k, sin, cos)
+            if cfg.rope:
+                sin, cos = rope_table(pos_arr.reshape(1),
+                                      cfg.resolved_head_dim, cfg.rope_theta)
+                q = rope_apply(q, sin, cos)
+                k = rope_apply(k, sin, cos)
             zero = jnp.zeros((), jnp.int32)
             at = (layer, zero, pos_arr, zero, zero)
             with jax.named_scope("kv_write"):
@@ -158,6 +168,7 @@ def attn_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
             for t in (k_all, v_all))
         with jax.named_scope("attn_core"):
             out1 = ops.decode_attention(q[:, 0], k_cache, v_cache, length,
+                                        scale=cfg.attention_multiplier,
                                         impl=cfg.attn_impl)
         out = out1[:, None]
         new_cache = {"k": k_all, "v": v_all}

@@ -100,9 +100,10 @@ def mlp_apply(params: Params, x: jax.Array, lowp: bool = False) -> jax.Array:
 # ---------------------------------------------------------------------------
 # Token embedding / unembedding.
 # ---------------------------------------------------------------------------
-def embed_init(rng, vocab: int, d: int, dtype, tie: bool) -> Params:
+def embed_init(rng, vocab: int, d: int, dtype, tie: bool,
+               scale: float = 0.02) -> Params:
     k1, k2 = jax.random.split(rng)
-    p = {"embedding": dense_init(k1, (vocab, d), dtype)}
+    p = {"embedding": dense_init(k1, (vocab, d), dtype, scale)}
     if not tie:
         p["unembed"] = dense_init(k2, (d, vocab), dtype)
     return p

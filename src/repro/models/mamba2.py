@@ -110,12 +110,14 @@ def _gated_norm(y: jax.Array, z: jax.Array, scale: jax.Array,
             ).astype(y.dtype)
 
 
+@jax.named_scope("mamba")
 def mamba_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
                 mode: str, cache: Optional[Params] = None, layer=None
                 ) -> Tuple[jax.Array, Optional[Params]]:
     """x: (b, s, d) -> (out, new_cache). In decode, ``cache`` is stacked
-    over layers and ``layer`` indexes it; the state is small, so it is
-    read at that index and written back there whole."""
+    over layers and ``layer`` indexes it; the state is read at that index
+    and written back there whole. Named scopes: ``mamba`` around it all,
+    ``conv``, ``ssd`` and ``gated_norm`` inside."""
     m, di, nh = _dims(cfg)
     n, p = m.d_state, m.headdim
     b, s, d = x.shape
@@ -125,14 +127,16 @@ def mamba_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
     A = -jnp.exp(params["A_log"])
 
     if mode in ("train", "prefill"):
-        xbc_c = _causal_conv(xbc, params["conv_w"], params["conv_b"])
+        with jax.named_scope("conv"):
+            xbc_c = _causal_conv(xbc, params["conv_w"], params["conv_b"])
         xs = xbc_c[..., :di].reshape(b, s, nh, p)
         B = xbc_c[..., di: di + n]
         C = xbc_c[..., di + n:]
-        dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
-                             + params["dt_bias"][None, None])
-        y, state = ops.ssd(xs, dt, A, B, C, params["D"],
-                           chunk=m.chunk_size)
+        with jax.named_scope("ssd"):
+            dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
+                                 + params["dt_bias"][None, None])
+            y, state = ops.ssd(xs, dt, A, B, C, params["D"],
+                               chunk=m.chunk_size)
         y = y.reshape(b, s, di)
         new_cache = None
         if mode == "prefill":
@@ -147,24 +151,28 @@ def mamba_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
         cur = jax.tree.map(
             lambda t: jax.lax.dynamic_index_in_dim(t, layer, keepdims=False),
             cache)
-        conv_hist = jnp.concatenate([cur["conv"], xbc], axis=1)
-        w, bias = params["conv_w"], params["conv_b"]
-        acc = jnp.einsum("bkc,kc->bc", conv_hist.astype(jnp.float32),
-                         w.astype(jnp.float32))
-        xbc_c = jax.nn.silu(acc + bias.astype(jnp.float32))[:, None].astype(x.dtype)
+        with jax.named_scope("conv"):
+            conv_hist = jnp.concatenate([cur["conv"], xbc], axis=1)
+            w, bias = params["conv_w"], params["conv_b"]
+            acc = jnp.einsum("bkc,kc->bc", conv_hist.astype(jnp.float32),
+                             w.astype(jnp.float32))
+            acc = jax.nn.silu(acc + bias.astype(jnp.float32))
+            xbc_c = acc[:, None].astype(x.dtype)
         xs = xbc_c[..., :di].reshape(b, nh, p)
         B = xbc_c[:, 0, di: di + n]
         C = xbc_c[:, 0, di + n:]
-        dt = jax.nn.softplus(dt_raw[:, 0].astype(jnp.float32)
-                             + params["dt_bias"][None])
-        y1, state = ssd_decode_ref(xs, dt, A, B, C, params["D"],
-                                   cur["ssd"])
+        with jax.named_scope("ssd"):
+            dt = jax.nn.softplus(dt_raw[:, 0].astype(jnp.float32)
+                                 + params["dt_bias"][None])
+            y1, state = ssd_decode_ref(xs, dt, A, B, C, params["D"],
+                                       cur["ssd"])
         y = y1.reshape(b, 1, di)
         new_cache = jax.tree.map(
             lambda t, u: jax.lax.dynamic_update_index_in_dim(
                 t, u.astype(t.dtype), layer, 0),
             cache, {"conv": conv_hist[:, 1:], "ssd": state})
 
-    y = _gated_norm(y, z, params["norm_scale"], cfg.norm_eps)
+    with jax.named_scope("gated_norm"):
+        y = _gated_norm(y, z, params["norm_scale"], cfg.norm_eps)
     y = shard(y, ("batch", "seq", "mlp_act"))
     return jnp.einsum("bse,ed->bsd", y, params["w_out"]), new_cache

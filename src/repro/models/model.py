@@ -27,10 +27,17 @@ Params = Dict[str, Any]
 # Params.
 # ---------------------------------------------------------------------------
 def init_params(cfg: ModelConfig, rng: jax.Array) -> Params:
+    """Random weights (``N(0, 0.02)`` matrices). Where the config sets an
+    embedding multiplier the embedding is drawn at ``0.02 / multiplier``,
+    so that the residual stream starts at the usual spread: a random tied
+    model whose embeddings came in twelve times larger would only repeat
+    its last token."""
     k_embed, k_stack = jax.random.split(rng)
+    scale = 0.02 / (cfg.embedding_multiplier or 1.0)
     return {
         "embed": embed_init(k_embed, cfg.vocab_size, cfg.d_model,
-                            jnp.dtype(cfg.dtype), cfg.tie_embeddings),
+                            jnp.dtype(cfg.dtype), cfg.tie_embeddings,
+                            scale),
         "blocks": stack.stack_init(k_stack, cfg),
         "final_norm": rmsnorm_init(cfg.d_model),
     }
@@ -57,6 +64,8 @@ def _embed_inputs(params: Params, cfg: ModelConfig, batch: Dict[str, Any]
                   ) -> jax.Array:
     with jax.named_scope("embed"):
         x = embed_apply(params["embed"], batch["tokens"])
+        if cfg.embedding_multiplier is not None:
+            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
         if "vision_embeds" in batch and batch["vision_embeds"] is not None:
             ve = batch["vision_embeds"].astype(x.dtype)
             x = jnp.concatenate([ve, x], axis=1)
@@ -80,6 +89,8 @@ def forward(params: Params, cfg: ModelConfig, batch: Dict[str, Any], *,
     with jax.named_scope("lm_head"):
         logits = unembed_apply(params["embed"] if cfg.tie_embeddings
                                else {**params["embed"]}, x)
+        if cfg.logits_scaling is not None:
+            logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     return logits, new_caches, aux
 
 
@@ -128,6 +139,8 @@ def loss_fn(params: Params, cfg: ModelConfig, batch: Dict[str, Any], *,
         def chunk_ce(args):
             xc, lc, mc = args
             logits = unembed_apply(params["embed"], xc).astype(jnp.float32)
+            if cfg.logits_scaling is not None:
+                logits = logits / cfg.logits_scaling
             return _ce_terms(logits, lc, mc)
 
         def body(carry, args):

@@ -1,5 +1,22 @@
-"""Top-k Mixture-of-Experts with capacity-bounded scatter dispatch.
+"""Top-k Mixture-of-Experts: capacity-bounded scatter dispatch for
+training, a dropless sorted dispatch for prefill and decode.
 
+The layer holds ``MoEConfig.held`` experts from ``expert_first`` (the
+chip's share under expert parallelism; all of them by default) and a
+shared expert where ``d_ff_shared`` is set. The router keeps all
+``num_experts`` outputs and its top-k; each token's top-k weights are
+the softmax over its top-k logits (the renormalised top-k of the full
+softmax). A share computes its held experts' part of the result for the
+tokens routed to them; the shared expert runs on every token.
+
+Serving (``mode`` prefill or decode) drops no token: the (token, choice)
+pairs routed to a held expert are sorted by expert and each expert's
+SwiGLU runs on its rows only, as one ``jax.lax.ragged_dot`` per matrix
+(a grouped matmul, XLA's own instruction on the TPU). The row buffer
+holds ``tokens * min(top_k, held)`` rows, the most a share can be
+routed, rounded up to whole tiles, so no routing overflows it.
+
+Training keeps the capacity dispatch and holds every expert. Its
 TPU-native formulation (GShard-style, grouped): tokens are grouped by their
 data shard, positions inside each expert's capacity buffer are computed with
 a group-local cumulative sum (no cross-shard prefix), tokens are
@@ -20,32 +37,50 @@ import jax.numpy as jnp
 
 from repro.config import ModelConfig, MoEConfig
 from repro.distributed.sharding import active_mesh, shard
-from repro.models.layers import dense_init
+from repro.models.layers import dense_init, mlp_apply, mlp_init, mlp_specs
 
 Params = Dict[str, Any]
 
+ROW_TILE = 128   # the dropless dispatch's row buffer is whole tiles of this
+
 
 def moe_init(rng, cfg: ModelConfig) -> Params:
+    """Keys: ``rng -> (router, gate, up, down, shared)``; each of gate, up
+    and down splits into one key per expert of the whole layer, of which
+    the share takes its own, so a share holds exactly the uncut layer's
+    experts."""
     moe = cfg.moe
     assert moe is not None
     d, f, e = cfg.d_model, moe.d_ff_expert, moe.num_experts
     dtype = jnp.dtype(cfg.dtype)
-    ks = jax.random.split(rng, 4)
-    return {
+    ks = jax.random.split(rng, 5)
+    first = moe.expert_first
+
+    def experts(key, shape):
+        keys = jax.random.split(key, e)[first: first + moe.held]
+        return jax.vmap(lambda k: dense_init(k, shape, dtype))(keys)
+
+    p = {
         "router": dense_init(ks[0], (d, e), jnp.float32),
-        "w_gate": dense_init(ks[1], (e, d, f), dtype),
-        "w_up": dense_init(ks[2], (e, d, f), dtype),
-        "w_down": dense_init(ks[3], (e, f, d), dtype),
+        "w_gate": experts(ks[1], (d, f)),
+        "w_up": experts(ks[2], (d, f)),
+        "w_down": experts(ks[3], (f, d)),
     }
+    if moe.d_ff_shared:
+        p["shared"] = mlp_init(ks[4], d, moe.d_ff_shared, dtype)
+    return p
 
 
 def moe_specs(cfg: ModelConfig) -> Params:
-    return {
+    p = {
         "router": ("p_embed", None),
         "w_gate": ("p_expert", "p_ff_fsdp", None),
         "w_up": ("p_expert", "p_ff_fsdp", None),
         "w_down": ("p_expert", None, "p_ff_fsdp"),
     }
+    if cfg.moe is not None and cfg.moe.d_ff_shared:
+        p["shared"] = mlp_specs()
+    return p
 
 
 def _num_groups() -> int:
@@ -65,11 +100,71 @@ def expert_capacity(tokens_per_group: int, moe: MoEConfig) -> int:
 
 
 def moe_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
-              rng: Optional[jax.Array] = None
+              mode: str = "train", rng: Optional[jax.Array] = None
               ) -> Tuple[jax.Array, jax.Array]:
-    """x: (b, s, d) -> (out, aux_loss)."""
+    """x: (b, s, d) -> (out, aux_loss). Named scopes: ``router``,
+    ``experts`` and ``shared_expert`` in prefill and decode."""
+    if mode == "train":
+        out, aux = _capacity_apply(params, cfg, x, rng)
+    else:
+        out, aux = _dropless_apply(params, cfg, x), jnp.zeros((), jnp.float32)
+    if "shared" in params:
+        with jax.named_scope("shared_expert"):
+            out = out + mlp_apply(params["shared"], x, lowp=cfg.mlp_lowp)
+    return out, aux
+
+
+def _dropless_apply(params: Params, cfg: ModelConfig, x: jax.Array
+                    ) -> jax.Array:
+    """The held experts' part of the routed result, for every token."""
+    moe = cfg.moe
+    b, s, d = x.shape
+    t, k, held = b * s, moe.top_k, moe.held
+    xt = x.reshape(t, d)
+    with jax.named_scope("router"):
+        logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                            params["router"])
+        top_l, top_i = jax.lax.top_k(logits, k)                 # (t, k)
+        weight = jax.nn.softmax(top_l, axis=-1).reshape(t * k)
+        # rows of held experts first, by expert; the rest sort last
+        local = top_i.reshape(t * k) - moe.expert_first
+        group = jnp.where((local >= 0) & (local < held), local, held)
+        # whole tiles of 128 rows: XLA on the TPU keeps a ragged dot of
+        # such a row count as its own instruction, and expands any other
+        # into one dense product per expert
+        rows = -(-t * min(k, held) // ROW_TILE) * ROW_TILE
+        order = jnp.argsort(group, stable=True)
+        order = jnp.pad(order, (0, max(0, rows - t * k)))[:rows]
+        sizes = jnp.bincount(group, length=held + 1)[:held]
+        live = jnp.arange(rows) < jnp.sum(sizes)
+        token = order // k
+        weight = jnp.where(live, weight[order], 0.0)
+    with jax.named_scope("experts"):
+        xs = xt[token]                                          # (rows, d)
+        g = jax.lax.ragged_dot(xs, params["w_gate"], sizes)
+        u = jax.lax.ragged_dot(xs, params["w_up"], sizes)
+        if cfg.mlp_lowp:
+            h = jax.nn.silu(g) * u
+        else:
+            h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
+        y = jax.lax.ragged_dot(h, params["w_down"], sizes)      # (rows, d)
+        # rows past the groups are not defined by ragged_dot: select, do
+        # not multiply
+        y = jnp.where(live[:, None], y.astype(jnp.float32)
+                      * weight[:, None], 0.0)
+        out = jnp.zeros((t, d), jnp.float32).at[token].add(y)
+    return shard(out.astype(x.dtype).reshape(b, s, d),
+                 ("batch", "seq", "embed_act"))
+
+
+def _capacity_apply(params: Params, cfg: ModelConfig, x: jax.Array,
+                    rng: Optional[jax.Array]
+                    ) -> Tuple[jax.Array, jax.Array]:
     moe = cfg.moe
     assert moe is not None
+    if moe.cut:
+        raise ValueError("the capacity dispatch holds every expert; "
+                         f"this layer holds {moe.held} of {moe.num_experts}")
     b, s, d = x.shape
     e, k = moe.num_experts, moe.top_k
     tokens = b * s

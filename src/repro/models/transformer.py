@@ -79,6 +79,15 @@ def layer_specs(cfg: ModelConfig, i: int) -> Params:
     return p
 
 
+def _residual(cfg: ModelConfig, x: jax.Array, branch: jax.Array
+              ) -> jax.Array:
+    """``x`` plus the branch, scaled by ``cfg.residual_multiplier`` where
+    the config sets it."""
+    if cfg.residual_multiplier is not None:
+        branch = branch * jnp.asarray(cfg.residual_multiplier, branch.dtype)
+    return x + branch
+
+
 def layer_apply(params: Params, cfg: ModelConfig, i_sig: Tuple[str, str],
                 x: jax.Array, *, mode: str, cache: Optional[Params],
                 pos, max_len: Optional[int] = None, layer=None
@@ -97,7 +106,7 @@ def layer_apply(params: Params, cfg: ModelConfig, i_sig: Tuple[str, str],
     else:
         mix, new_cache = mamba_mod.mamba_apply(
             params["mixer"], cfg, h, mode=mode, cache=cache, layer=layer)
-    x = x + mix
+    x = _residual(cfg, x, mix)
     aux = jnp.zeros((), jnp.float32)
     if ffn != "none":
         with jax.named_scope("norm"):
@@ -105,10 +114,10 @@ def layer_apply(params: Params, cfg: ModelConfig, i_sig: Tuple[str, str],
                               lowp=cfg.mlp_lowp)
         with jax.named_scope("moe" if ffn == "moe" else "mlp"):
             if ffn == "moe":
-                f, aux = moe_mod.moe_apply(params["ffn"], cfg, h)
+                f, aux = moe_mod.moe_apply(params["ffn"], cfg, h, mode=mode)
             else:
                 f = mlp_apply(params["ffn"], h, lowp=cfg.mlp_lowp)
-        x = x + f
+        x = _residual(cfg, x, f)
     x = shard(x, ("batch", "seq", "embed_act"))
     return x, new_cache, aux
 

@@ -32,7 +32,9 @@ the yielded dict before exit stay in memory):
   ``queued_ns``: submit to admit), ``repro.batcher.sample``,
   ``repro.batcher.update`` (``finished``: the rids that completed);
 * ``repro.engine.prefill``, ``repro.engine.decode``: the uploads and the
-  dispatch of the jitted function;
+  dispatch of the jitted function (``state_bytes``, for a model with
+  Mamba layers: the recurrent state the prefill makes for its slot, or
+  that the decode step reads and writes back for every slot);
 * ``repro.compile`` (``event``, ``duration_s``).
 
 Counters: ``repro.gate`` once per tick per tenant (``rate``: the

@@ -48,6 +48,15 @@ def dequantize_params(params: Params) -> Params:
 
 
 
+def recurrent_state_bytes(cfg: ModelConfig) -> int:
+    """Bytes of recurrent state one slot holds: the conv window and the
+    SSD state of every Mamba layer (0 for attention alone)."""
+    caches = jax.eval_shape(lambda: lm.init_caches(cfg, 1, 1))
+    return sum(leaf.size * leaf.dtype.itemsize
+               for c in caches if c is not None and "ssd" in c
+               for leaf in jax.tree.leaves(c))
+
+
 class ServingEngine:
     def __init__(self, cfg: ModelConfig, scfg: Optional[ServeConfig] = None,
                  mesh=None, rules: Optional[RuleSet] = None,
@@ -59,6 +68,7 @@ class ServingEngine:
         self.scan = scan
         self.max_len = ops.cache_len(self.scfg.max_seq_len)
         self.params: Optional[Params] = None
+        self.state_bytes = recurrent_state_bytes(cfg)
 
         def _prefill(params, batch):
             with use_sharding(self.mesh, self.rules):
@@ -89,17 +99,23 @@ class ServingEngine:
     # ------------------------------------------------------------------
     def prefill(self, prompt: np.ndarray):
         """Upload one prompt and dispatch the prefill at batch 1; returns
-        (last-position logits, caches)."""
+        (last-position logits, caches). Its span carries ``state_bytes``,
+        the recurrent state the prefill makes for its slot."""
         rec = obs.RECORDER
-        with obs.OFF if rec is None else rec.span("repro.engine.prefill"):
+        with obs.OFF if rec is None else rec.span(
+                "repro.engine.prefill", state_bytes=self.state_bytes or None):
             batch = {"tokens": jnp.asarray(prompt[None, :])}
             return self.prefill_fn(self.params, batch)
 
     def decode(self, tokens: np.ndarray, caches, positions: np.ndarray):
         """Upload each slot's last token and position and dispatch one
-        decode step; returns (logits, caches)."""
+        decode step; returns (logits, caches). Its span carries
+        ``state_bytes``, the recurrent state of every slot, which the step
+        reads and writes back."""
         rec = obs.RECORDER
-        with obs.OFF if rec is None else rec.span("repro.engine.decode"):
+        with obs.OFF if rec is None else rec.span(
+                "repro.engine.decode",
+                state_bytes=self.state_bytes * len(tokens) or None):
             toks = jnp.asarray(tokens[:, None], jnp.int32)
             pos = jnp.asarray(positions, jnp.int32)
             return self.decode_fn(self.params, toks, caches, pos)

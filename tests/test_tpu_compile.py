@@ -168,3 +168,65 @@ def test_train_step_compiles(tpu_branch, one_chip, spec):
     text = _compiled_text(make_train_step(cfg, tcfg),
                           _placed(params, one_chip), opt, batch)
     assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# granite-4.0-h-small as the chip benchmark cuts it: one period of 9 Mamba
+# layers and 1 attention layer, 9 of 72 experts held, at published widths.
+# ---------------------------------------------------------------------------
+def _granite_cut():
+    cfg = get_config("granite-4.0-h-small")
+    return cfg.replace(num_layers=10, layer_pattern=cfg.layer_kinds()[:10],
+                       moe=dataclasses.replace(cfg.moe, experts_held=9))
+
+
+def test_ssd_scan_compiles_at_granite_widths(spec):
+    """128 heads of 64, state 128, chunk 256; the kernel keeps its name."""
+    m = get_config("granite-4.0-h-small").mamba
+    h, p, n, s = 128, m.headdim, m.d_state, 1280
+    f32 = jnp.float32
+
+    def scan(x, dt, A, B, C, D):
+        return ssd_scan(x, dt, A, B, C, D, chunk=m.chunk_size)
+
+    text = _compiled_text(scan, spec((1, s, h, p)), spec((1, s, h), f32),
+                          spec((h,), f32), spec((1, s, n)), spec((1, s, n)),
+                          spec((h,), f32))
+    assert "%ssd_scan" in text
+
+
+def test_granite_decode_step_compiles(tpu_branch, one_chip, spec):
+    """The hybrid decode step with its caches (SSD and conv state, KV)
+    donated: every cache aliases the output, and the held experts run as
+    XLA's ragged dot, not as one dense product per expert."""
+    cfg = _granite_cut()
+    params = _placed(lm.param_shapes(cfg), one_chip)
+    caches = _placed(jax.eval_shape(lambda: lm.init_caches(cfg, 4, 512)),
+                     one_chip)
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(caches))
+
+    def step(params, tokens, caches, pos):
+        return lm.decode_step(params, cfg, tokens, caches, pos)
+
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        params, spec((4, 1), jnp.int32), caches,
+        spec((4,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ragged" in text
+    assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
+
+
+def test_granite_prefill_compiles_at_unaligned_length(tpu_branch, one_chip,
+                                                      spec):
+    """A prompt of 300 tokens: the SSD scan pads to its chunk and the
+    expert layer's row buffer (300 x 9, rounded to whole tiles) stays a
+    ragged dot."""
+    cfg = _granite_cut()
+    params = _placed(lm.param_shapes(cfg), one_chip)
+
+    def prefill(params, tokens):
+        return lm.prefill(params, cfg, {"tokens": tokens}, max_len=512)
+
+    text = _compiled_text(prefill, params, spec((1, 300), jnp.int32))
+    assert "%ssd_scan" in text and "ragged" in text

@@ -26,8 +26,8 @@ def run() -> None:
     emit("kern/attention_1k", us, f"gflops_s={flops/(us*1e-6)/1e9:.1f}")
 
     qd = jnp.asarray(rng.standard_normal((8, hq, d)), jnp.bfloat16)
-    kd = jnp.asarray(rng.standard_normal((8, 4096, hkv, d)), jnp.bfloat16)
-    vd = jnp.asarray(rng.standard_normal((8, 4096, hkv, d)), jnp.bfloat16)
+    kd = jnp.asarray(rng.standard_normal((8, hkv, 4096, d)), jnp.bfloat16)
+    vd = jnp.asarray(rng.standard_normal((8, hkv, 4096, d)), jnp.bfloat16)
     length = jnp.full((8,), 4096, jnp.int32)
     fd = jax.jit(lambda q, k, v, l: ref.decode_attention_ref(q, k, v, l))
     us = time_fn(fd, qd, kd, vd, length, iters=3)

@@ -108,14 +108,15 @@ def phase_kernels(seed: int) -> None:
             want = ref.attention_ref(q, k, v)
         _check(f"flash_attention s={s}" + ("" if s % 128 == 0
                                           else " (ops, padded)"), out, want)
-    # decode attention over a 4-slot cache of ops.cache_len(440) = 512
+    # decode attention over a 4-slot head-major cache of ops.cache_len(440)
+    # = 512, stacked over two layers and read at the second
     skv = ops.cache_len(440)
-    q, k, v = normal((4, hq, d)), normal((4, skv, hkv, d)), \
-        normal((4, skv, hkv, d))
+    q, k, v = normal((4, hq, d)), normal((2, 4, hkv, skv, d)), \
+        normal((2, 4, hkv, skv, d))
     length = jax.random.randint(next(keys), (4,), 1, skv + 1)
-    out = decode_attention(q, k, v, length)
+    out = decode_attention(q, k, v, length, 1)
     with exact:
-        want = ref.decode_attention_ref(q, k, v, length)
+        want = ref.decode_attention_ref(q, k, v, length, 1)
     _check(f"decode_attention b=4 skv={skv}", out, want)
     # rmsnorm on prefill rows (not a multiple of the row block) and decode
     w = 1.0 + 0.1 * normal((cfg.d_model,), jnp.float32)

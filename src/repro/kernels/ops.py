@@ -133,17 +133,22 @@ def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
     return _ref.attention_ref(q, k, v, causal=causal, scale=scale)
 
 
-def decode_attention(q, k, v, length, *, scale: Optional[float] = None,
-                     impl: str = "ref"):
-    """q: (b, hq, d); k, v: (b, L, hkv, d) with L from :func:`cache_len`."""
+def decode_attention(q, k, v, length, layer=None, *,
+                     scale: Optional[float] = None, impl: str = "ref"):
+    """q: (b, hq, d); k, v: the head-major cache, (b, hkv, L, d), or
+    stacked over layers, (layers, b, hkv, L, d), with ``layer`` picking
+    one; L from :func:`cache_len`. The kernel reads the stacked cache in
+    place."""
     if on_tpu():
+        layer = jnp.asarray(0 if layer is None else layer, jnp.int32)
         return _grad_by_ref(
             functools.partial(_pl_decode, scale=scale),
             functools.partial(_ref.decode_attention_ref, scale=scale),
-            q, k, v, length)
+            q, k, v, length, layer)
     if impl == "chunked":   # "chunked" config selects low-cast decode
-        return _ref.decode_attention_lowcast(q, k, v, length, scale=scale)
-    return _ref.decode_attention_ref(q, k, v, length, scale=scale)
+        return _ref.decode_attention_lowcast(q, k, v, length, layer,
+                                             scale=scale)
+    return _ref.decode_attention_ref(q, k, v, length, layer, scale=scale)
 
 
 def int8_matmul(x_q, sx, w_q, sw, out_dtype=jnp.float32):

@@ -70,14 +70,32 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 # ---------------------------------------------------------------------------
 # Decode attention: single query token against a (possibly longer) cache.
-# q: (b, hq, d)   k, v: (b, skv, hkv, d)   length: (b,) valid cache length
+# q: (b, hq, d)   length: (b,) valid cache length
+# k, v: (b, hkv, skv, d), head-major, or stacked over layers as
+#       (layers, b, hkv, skv, d) with ``layer`` picking one
 # ---------------------------------------------------------------------------
+def _decode_layer(k: jax.Array, v: jax.Array, layer):
+    if k.ndim == 4:
+        return k, v
+    return (jax.lax.dynamic_index_in_dim(t, layer, keepdims=False)
+            for t in (k, v))
+
+
 def decode_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
-                         length: jax.Array, *, scale: float | None = None
-                         ) -> jax.Array:
-    out = attention_ref(q[:, None], k, v, causal=False, scale=scale,
-                        kv_len=length)
-    return out[:, 0]
+                         length: jax.Array, layer=None, *,
+                         scale: float | None = None) -> jax.Array:
+    k, v = _decode_layer(k, v, layer)
+    b, hq, d = q.shape
+    _, hkv, skv, _ = k.shape
+    g = hq // hkv
+    scale = scale if scale is not None else 1.0 / np.sqrt(d)
+    qf = q.reshape(b, hkv, g, d).astype(jnp.float32)
+    scores = jnp.einsum("bhgd,bhkd->bhgk", qf, k.astype(jnp.float32)) * scale
+    lmask = jnp.arange(skv)[None, :] < jnp.asarray(length).reshape(-1, 1)
+    scores = jnp.where(lmask[:, None, None], scores, NEG_INF)
+    p = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhgk,bhkd->bhgd", p, v.astype(jnp.float32))
+    return out.reshape(b, hq, d).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -195,25 +213,26 @@ def attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def decode_attention_lowcast(q: jax.Array, k: jax.Array, v: jax.Array,
-                             length: jax.Array, *,
+                             length: jax.Array, layer=None, *,
                              scale: float | None = None) -> jax.Array:
     """Decode attention without upcasting the KV cache: bf16/fp8 operands
     feed the dot directly with fp32 accumulation; only the (b, h, skv)
-    scores run in fp32."""
+    scores run in fp32. The cache layout is ``decode_attention_ref``'s."""
+    k, v = _decode_layer(k, v, layer)
     b, hq, d = q.shape
-    _, skv, hkv, _ = k.shape
+    _, hkv, skv, _ = k.shape
     g = hq // hkv
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
     qr = q.reshape(b, hkv, g, d).astype(k.dtype)
     s = jax.lax.dot_general(
-        qr, k, (((3,), (3,)), ((0, 1), (0, 2))),
+        qr, k, (((3,), (3,)), ((0, 1), (0, 1))),
         preferred_element_type=jnp.float32) * scale   # (b, hkv, g, skv)
     lmask = jnp.arange(skv)[None, None, None, :] < \
         jnp.asarray(length).reshape(b, 1, 1, 1)
     s = jnp.where(lmask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((3,), (1,)), ((0, 1), (0, 2))),
+        p.astype(v.dtype), v, (((3,), (2,)), ((0, 1), (0, 1))),
         preferred_element_type=jnp.float32)           # (b, hkv, g, d)
     return o.reshape(b, hq, d).astype(q.dtype)
 

@@ -6,8 +6,11 @@ Modes:
   * prefill — causal self-attention that also emits the KV cache laid out
               in the decode sharding (``kv_seq`` sequence-sharded)
   * decode  — one new token written at ``pos`` into the cache stacked over
-              layers, at the layer's index, and attended against
+              layers, at the layer's index, and attended against in place
               (flash-decode partial-softmax combine under GSPMD)
+
+The KV cache is head-major, ``(b, hkv, L, d)`` a layer and
+``(layers, b, hkv, L, d)`` stacked, the layout the decode kernel reads.
 
 With ``cfg.rope`` off (NoPE) no rotary embedding is applied in any mode;
 ``cfg.attention_multiplier``, where set, scales the scores in place of
@@ -62,15 +65,15 @@ def attn_specs(cfg: ModelConfig) -> Params:
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> Params:
     hkv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
     return {
-        "k": jnp.zeros((batch, max_len, hkv, hd), dtype),
-        "v": jnp.zeros((batch, max_len, hkv, hd), dtype),
+        "k": jnp.zeros((batch, hkv, max_len, hd), dtype),
+        "v": jnp.zeros((batch, hkv, max_len, hd), dtype),
     }
 
 
 def cache_specs() -> Params:
     return {
-        "k": ("batch", "kv_seq", "kv_heads_act", "head_dim_act"),
-        "v": ("batch", "kv_seq", "kv_heads_act", "head_dim_act"),
+        "k": ("batch", "kv_heads_act", "kv_seq", "head_dim_act"),
+        "v": ("batch", "kv_heads_act", "kv_seq", "head_dim_act"),
     }
 
 
@@ -115,14 +118,12 @@ def attn_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
         new_cache = None
         if mode == "prefill":
             with jax.named_scope("kv_write"):
-                kc, vc = k, v
+                kc, vc = (t.transpose(0, 2, 1, 3) for t in (k, v))
                 if max_len is not None and max_len > s:
-                    pad = ((0, 0), (0, max_len - s), (0, 0), (0, 0))
+                    pad = ((0, 0), (0, 0), (0, max_len - s), (0, 0))
                     kc, vc = jnp.pad(kc, pad), jnp.pad(vc, pad)
-                new_cache = {
-                    "k": shard(kc, ("batch", "kv_seq", "kv_heads_act", None)),
-                    "v": shard(vc, ("batch", "kv_seq", "kv_heads_act", None)),
-                }
+                spec = ("batch", "kv_heads_act", "kv_seq", None)
+                new_cache = {"k": shard(kc, spec), "v": shard(vc, spec)}
     else:  # decode
         assert cache is not None and pos is not None and layer is not None
         pos_arr = jnp.asarray(pos, jnp.int32)
@@ -139,12 +140,14 @@ def attn_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
                 sin, cos = sin[:, None], cos[:, None]       # (b, 1, d/2)
                 q = rope_apply(q, sin, cos)
                 k = rope_apply(k, sin, cos)
-            bidx = jnp.arange(b)
+            # one row of d per (slot, head): a write of whole (hkv, d)
+            # windows would have XLA lay the cache out head-minor
+            bidx = jnp.arange(b)[:, None]
+            hidx = jnp.arange(k.shape[2])[None, :]
+            at = (layer, bidx, hidx, pos_arr[:, None])
             with jax.named_scope("kv_write"):
-                k_all = cache["k"].at[layer, bidx, pos_arr].set(
-                    k[:, 0].astype(cdt))
-                v_all = cache["v"].at[layer, bidx, pos_arr].set(
-                    v[:, 0].astype(cdt))
+                k_all = cache["k"].at[at].set(k[:, 0].astype(cdt))
+                v_all = cache["v"].at[at].set(v[:, 0].astype(cdt))
             length = pos_arr + 1
         else:
             if cfg.rope:
@@ -153,21 +156,18 @@ def attn_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
                 q = rope_apply(q, sin, cos)
                 k = rope_apply(k, sin, cos)
             zero = jnp.zeros((), jnp.int32)
-            at = (layer, zero, pos_arr, zero, zero)
+            at = (layer, zero, zero, pos_arr, zero)
             with jax.named_scope("kv_write"):
-                k_all = jax.lax.dynamic_update_slice(
-                    cache["k"], k[None].astype(cdt), at)
-                v_all = jax.lax.dynamic_update_slice(
-                    cache["v"], v[None].astype(cdt), at)
+                k_all, v_all = (
+                    jax.lax.dynamic_update_slice(
+                        cache[n], t.transpose(0, 2, 1, 3)[None].astype(cdt),
+                        at)
+                    for n, t in (("k", k), ("v", v)))
             length = jnp.full((b,), pos_arr + 1, jnp.int32)
-        stacked = (None, "batch", "kv_seq", "kv_heads_act", None)
+        stacked = (None, "batch", "kv_heads_act", "kv_seq", None)
         k_all, v_all = shard(k_all, stacked), shard(v_all, stacked)
-        k_cache, v_cache = (
-            shard(jax.lax.dynamic_index_in_dim(t, layer, keepdims=False),
-                  stacked[1:])
-            for t in (k_all, v_all))
         with jax.named_scope("attn_core"):
-            out1 = ops.decode_attention(q[:, 0], k_cache, v_cache, length,
+            out1 = ops.decode_attention(q[:, 0], k_all, v_all, length, layer,
                                         scale=cfg.attention_multiplier,
                                         impl=cfg.attn_impl)
         out = out1[:, None]

@@ -107,15 +107,31 @@ class ServingEngine:
             batch = {"tokens": jnp.asarray(prompt[None, :])}
             return self.prefill_fn(self.params, batch)
 
+    def kv_blocks(self, positions: np.ndarray):
+        """(blocks the decode kernel fetches per attention layer, blocks
+        in the cache per layer) for slots at ``positions``: each slot's
+        KV blocks up to the one holding its new position, of all
+        ``max_len / block`` blocks; (None, None) without attention."""
+        if not self.cfg.uses_attention:
+            return None, None
+        bk = min(ops.DECODE_BLOCK, self.max_len)
+        lengths = np.asarray(positions, np.int64) + 1
+        return (int(np.sum(-(-lengths // bk))),
+                len(lengths) * (self.max_len // bk))
+
     def decode(self, tokens: np.ndarray, caches, positions: np.ndarray):
         """Upload each slot's last token and position and dispatch one
         decode step; returns (logits, caches). Its span carries
         ``state_bytes``, the recurrent state of every slot, which the step
-        reads and writes back."""
+        reads and writes back, and ``kv_blocks`` of ``kv_blocks_all``
+        (:meth:`kv_blocks`)."""
         rec = obs.RECORDER
+        blocks, blocks_all = (None, None) if rec is None else \
+            self.kv_blocks(positions)
         with obs.OFF if rec is None else rec.span(
                 "repro.engine.decode",
-                state_bytes=self.state_bytes * len(tokens) or None):
+                state_bytes=self.state_bytes * len(tokens) or None,
+                kv_blocks=blocks, kv_blocks_all=blocks_all):
             toks = jnp.asarray(tokens[:, None], jnp.int32)
             pos = jnp.asarray(positions, jnp.int32)
             return self.decode_fn(self.params, toks, caches, pos)

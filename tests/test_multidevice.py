@@ -168,7 +168,7 @@ for pos in (jnp.asarray([12, 9], jnp.int32), 12):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5)
     specs = [c["k"].sharding.spec for c in c4]
-    assert all(s[2] == "model" for s in specs), specs
+    assert all(s[3] == "model" for s in specs), specs
     assert specs == [c["k"].sharding.spec for c in before], specs
 k = c4[0]["k"]
 layer_shard = int(np.prod(k.sharding.shard_shape(k.shape)[1:]))

@@ -185,6 +185,23 @@ def test_decode_step_carries_named_scopes():
         assert f"/{scope}/" in text, scope
 
 
+def test_decode_span_counts_kv_blocks():
+    """``kv_blocks``: each slot's KV blocks up to the one holding its new
+    position (what the decode kernel's index map fetches per layer);
+    ``kv_blocks_all``: every block of every slot."""
+    cfg = smoke_config(get_config("internlm2-1.8b"))
+    eng = ServingEngine(cfg, ServeConfig(max_seq_len=600))
+    assert eng.max_len == 3 * 256
+    eng.init_random(0)
+    positions = np.array([0, 255, 256, 700])
+    caches = lm.init_caches(cfg, len(positions), eng.max_len)
+    with obs.recording() as rec:
+        eng.decode(np.zeros(len(positions), np.int64), caches, positions)
+    (span,) = rec.named("repro.engine.decode")
+    assert span.args["kv_blocks"] == 1 + 1 + 2 + 3
+    assert span.args["kv_blocks_all"] == 4 * 3
+
+
 def test_serve_report_reads_the_spans(tmp_path):
     from repro.launch.serve import serve
 

@@ -28,6 +28,10 @@ def _ops_calls(rng):
     q4 = jnp.asarray(rng.standard_normal((1, 8, 2, 16)), f32)
     q3 = jnp.asarray(rng.standard_normal((1, 2, 16)), f32)
     kv = jnp.asarray(rng.standard_normal((1, 8, 2, 16)), f32)
+    # head-major decode caches: one layer, and three stacked
+    cache = jnp.asarray(rng.standard_normal((1, 2, 8, 16)), f32)
+    stacked = jnp.asarray(rng.standard_normal((3, 1, 2, 8, 16)), f32)
+    length = jnp.array([5], jnp.int32)
     xs = jnp.asarray(rng.standard_normal((1, 8, 2, 4)), f32)
     dt = jnp.full((1, 8, 2), 0.1, f32)
     bc = jnp.asarray(rng.standard_normal((1, 8, 4)), f32)
@@ -37,8 +41,10 @@ def _ops_calls(rng):
                     "_pl_rmsnorm"),
         "attention": (ops.attention, (q4, kv, kv), {}, "_pl_flash"),
         "decode_attention": (ops.decode_attention,
-                             (q3, kv, kv, jnp.array([5], jnp.int32)), {},
-                             "_pl_decode"),
+                             (q3, cache, cache, length), {}, "_pl_decode"),
+        "decode_attention_stacked": (
+            ops.decode_attention, (q3, stacked, stacked, length, 1), {},
+            "_pl_decode"),
         "int8_matmul": (ops.int8_matmul,
                         (xq, jnp.ones((4,), f32), xq.T, jnp.ones((4,), f32)),
                         {}, "_pl_int8"),
@@ -47,10 +53,12 @@ def _ops_calls(rng):
     }
 
 
-OPS = ["rmsnorm", "attention", "decode_attention", "int8_matmul", "ssd"]
+OPS = ["rmsnorm", "attention", "decode_attention",
+       "decode_attention_stacked", "int8_matmul", "ssd"]
 # The floating-point arguments of each call, which a gradient can take.
 FLOAT_ARGS = {"rmsnorm": (0, 1), "attention": (0, 1, 2),
-              "decode_attention": (0, 1, 2), "int8_matmul": (1, 3),
+              "decode_attention": (0, 1, 2),
+              "decode_attention_stacked": (0, 1, 2), "int8_matmul": (1, 3),
               "ssd": (0, 1, 2, 3, 4, 5)}
 
 
@@ -168,7 +176,7 @@ def test_kernels_name_untileable_shapes():
     with pytest.raises(ValueError, match="sq=200"):
         flash_attention(q, q, q, interpret=True)
     from repro.kernels.decode_attention import decode_attention
-    k = jnp.zeros((1, 300, 2, 16))
+    k = jnp.zeros((1, 2, 300, 16))
     with pytest.raises(ValueError, match="cache length 300"):
         decode_attention(jnp.zeros((1, 2, 16)), k, k, jnp.array([3]),
                          interpret=True)

@@ -76,7 +76,7 @@ def test_resolver_properties(dims, names):
 
 def test_serve_rules_batch1_shards_kvseq_everywhere():
     rules = serve_rules(False, batch1=True)
-    spec = resolve_spec((1, 524288, 8, 128),
-                        ("batch", "kv_seq", "kv_heads_act", None),
+    spec = resolve_spec((1, 8, 524288, 128),
+                        ("batch", "kv_heads_act", "kv_seq", None),
                         rules, MESH)
-    assert spec[1] == ("pod", "data", "model")
+    assert spec[2] == ("pod", "data", "model")

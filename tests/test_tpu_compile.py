@@ -9,6 +9,7 @@ below, never at import time, so that under several pytest
 workers only the worker given this file loads the TPU compiler.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -73,15 +74,19 @@ def test_flash_attention_compiles(spec, s):
     assert "tpu_custom_call" in text
 
 
-def test_decode_attention_compiles(spec):
-    cfg = get_config(ARCH)
+@pytest.mark.parametrize("arch", [ARCH, "granite-4.0-h-small"])
+def test_decode_attention_compiles(spec, arch):
+    """On a stacked head-major cache at g = 2 (internlm2) and g = 4
+    (granite): the kernel keeps its name and no transpose is made."""
+    cfg = get_config(arch)
     hq, hkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     skv = ops.cache_len(440)
     assert skv == 512
-    text = _compiled_text(decode_attention, spec((4, hq, d)),
-                          spec((4, skv, hkv, d)), spec((4, skv, hkv, d)),
-                          spec((4,), jnp.int32))
-    assert "tpu_custom_call" in text
+    cache = spec((3, 4, hkv, skv, d))
+    text = _compiled_text(decode_attention, spec((4, hq, d)), cache, cache,
+                          spec((4,), jnp.int32), spec((), jnp.int32))
+    assert "tpu_custom_call" in text and "%decode_attention" in text
+    assert "transpose(" not in text
 
 
 @pytest.mark.parametrize("rows", [300, 4], ids=["prefill", "decode"])
@@ -118,16 +123,21 @@ def _placed(tree, one_chip):
         tree)
 
 
-def test_decode_step_compiles(tpu_branch, one_chip, spec):
+@pytest.mark.parametrize("slots,positions", [(4, 342 + 32 + 8), (16, 3072)],
+                         ids=["small", "chat"])
+def test_decode_step_compiles(tpu_branch, one_chip, spec, slots, positions):
     """The decode step with its caches donated, as the engine jits it:
     the Pallas kernel is in the program, the whole cache aliases the
-    output, and no second copy of it is made (temp below a quarter of the
-    cache)."""
+    output, and the kernel reads it in place: no transpose or copy under
+    ``attn_core``, and temp below a quarter of one layer's K and V. At
+    the chat cell's 16 x 3072 a copy of one layer's K and V would not fit
+    the chip's fast memory and would show in temp."""
     cfg = get_config(ARCH)
-    max_len = ops.cache_len(342 + 32 + 8)
+    max_len = ops.cache_len(positions)
     params = _placed(lm.param_shapes(cfg), one_chip)
-    caches = _placed(jax.eval_shape(lambda: lm.init_caches(cfg, 4, max_len)),
-                     one_chip)
+    caches = _placed(
+        jax.eval_shape(lambda: lm.init_caches(cfg, slots, max_len)),
+        one_chip)
     cache_bytes = sum(a.size * a.dtype.itemsize
                       for a in jax.tree.leaves(caches))
 
@@ -135,12 +145,16 @@ def test_decode_step_compiles(tpu_branch, one_chip, spec):
         return lm.decode_step(params, cfg, tokens, caches, pos)
 
     compiled = jax.jit(step, donate_argnums=(2,)).lower(
-        params, spec((4, 1), jnp.int32), caches,
-        spec((4,), jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        params, spec((slots, 1), jnp.int32), caches,
+        spec((slots,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    moved = [line for line in text.splitlines() if "attn_core" in line
+             and re.search(r"= \S+ (transpose|copy)\(", line)]
+    assert not moved, moved
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == cache_bytes
-    assert mem.temp_size_in_bytes < cache_bytes / 4
+    assert mem.temp_size_in_bytes < cache_bytes / cfg.num_layers / 4
 
 
 def test_prefill_compiles_at_unaligned_length(tpu_branch, one_chip, spec):

@@ -53,10 +53,12 @@ def run(archs=None) -> None:
         lg, caches = eng.prefill_fn(eng.params,
                                     {"tokens": jnp.ones((2, 16), jnp.int32)})
         tok = jnp.ones((2, 1), jnp.int32)
-        lg2, caches = eng.decode_fn(eng.params, tok, caches, 16)
+        lg2, caches = eng.decode_fn(eng.params, tok, caches,
+                                    jnp.full((2,), 16, jnp.int32))
         jax.block_until_ready(lg2)
         t0 = _t.perf_counter()
-        lg2, caches = eng.decode_fn(eng.params, tok, caches, 17)
+        lg2, caches = eng.decode_fn(eng.params, tok, caches,
+                                    jnp.full((2,), 17, jnp.int32))
         jax.block_until_ready(lg2)
         us = (_t.perf_counter() - t0) * 1e6
         emit(f"step/decode_{arch}", us, f"tokens_s={2/(us*1e-6):.0f}")

@@ -50,7 +50,7 @@ def main() -> None:
                             fig8_hw_codec, fig11_dl_serving,
                             fig12_dl_proportionality, fig13_collaborative,
                             fig14_mixed_tenancy, fig15_dvfs_pareto,
-                            fig16_fleet, roofline_table, table2_microbench,
+                            fig16_fleet, table2_microbench,
                             table3_network_bound, table4_tco, table5_tpc)
 
     suites = {
@@ -72,7 +72,6 @@ def main() -> None:
         "kernels": bench_kernels.run,
         "steps": bench_step.run,
         "pool": bench_pool.run,
-        "roofline": roofline_table.run,
     }
     if args.list:
         for name in suites:

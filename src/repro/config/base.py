@@ -7,7 +7,6 @@ helpers to derive reduced "smoke" configs. Every assigned architecture in
 from __future__ import annotations
 
 import dataclasses
-import math
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -31,10 +30,6 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_jitter: float = 0.0
     aux_loss_weight: float = 0.01
-    # dispatch variant: "v1" (padded buffer + extra overflow row) or
-    # "v2" (drop-mode scatter into an expert-flat buffer that shards
-    # cleanly over the model axis — the EP-collective hillclimb lever)
-    dispatch: str = "v1"
     # width of a shared expert that every token passes through (0: none)
     d_ff_shared: int = 0
     # the chip's share of the experts: experts [expert_first, expert_first
@@ -100,14 +95,6 @@ class ModelConfig:
     mamba: Optional[MambaConfig] = None
     # 'attn'/'mamba' pattern; None => all-attn (or all-mamba for family=ssm).
     layer_pattern: Optional[Tuple[str, ...]] = None
-    # attention implementation on the XLA (non-Pallas) path:
-    # "ref" (materialized scores) | "chunked" (online-softmax q-chunks,
-    # native-dtype dots — flash-attention access pattern in pure jnp)
-    attn_impl: str = "ref"
-    attn_chunk: int = 512
-    # compute activation nonlinearities in the storage dtype (bf16) instead
-    # of upcasting to fp32 (halves elementwise HBM traffic in the FFN)
-    mlp_lowp: bool = False
     # Modality frontend stub: number of prepended embedding positions the
     # frontend contributes (patch/frame embeddings come precomputed via
     # input_specs()).
@@ -154,13 +141,7 @@ class ModelConfig:
     def uses_mamba(self) -> bool:
         return any(k == MAMBA for k in self.layer_kinds())
 
-    @property
-    def subquadratic(self) -> bool:
-        """True if the arch can run 500k-context decode per the spec
-        (SSM/hybrid/linear-attention)."""
-        return self.family in ("ssm", "hybrid")
-
-    # ----- parameter counting (analytic; used for roofline MODEL_FLOPS) ----
+    # ----- parameter counting (analytic) ----
     def param_counts(self) -> Dict[str, float]:
         d, hd = self.d_model, self.resolved_head_dim
         counts: Dict[str, float] = {}
@@ -223,62 +204,8 @@ class ModelConfig:
 
 
 # ---------------------------------------------------------------------------
-# Shapes (assigned input-shape set).
+# Train / serve configs.
 # ---------------------------------------------------------------------------
-@dataclass(frozen=True)
-class ShapeSpec:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str  # "train" | "prefill" | "decode"
-
-    @property
-    def is_decode(self) -> bool:
-        return self.kind == "decode"
-
-
-TRAIN_4K = ShapeSpec("train_4k", 4096, 256, "train")
-PREFILL_32K = ShapeSpec("prefill_32k", 32768, 32, "prefill")
-DECODE_32K = ShapeSpec("decode_32k", 32768, 128, "decode")
-LONG_500K = ShapeSpec("long_500k", 524288, 1, "decode")
-
-ALL_SHAPES: Tuple[ShapeSpec, ...] = (TRAIN_4K, PREFILL_32K, DECODE_32K, LONG_500K)
-SHAPES: Dict[str, ShapeSpec] = {s.name: s for s in ALL_SHAPES}
-
-
-def shape_applicable(cfg: ModelConfig, shape: ShapeSpec) -> Tuple[bool, str]:
-    """Per-spec applicability: (sanctioned, note).
-
-    long_500k is sanctioned only for sub-quadratic archs; for pure
-    full-attention archs we may still compile it as a *bonus* cell.
-    """
-    if shape.name == "long_500k" and not cfg.subquadratic:
-        return False, ("spec-sanctioned skip: pure full-attention arch; "
-                       "compiled as bonus cell (decode attention is O(S))")
-    return True, ""
-
-
-# ---------------------------------------------------------------------------
-# Mesh / run configs.
-# ---------------------------------------------------------------------------
-@dataclass(frozen=True)
-class MeshConfig:
-    shape: Tuple[int, ...] = (16, 16)
-    axes: Tuple[str, ...] = ("data", "model")
-
-    @property
-    def num_devices(self) -> int:
-        return int(math.prod(self.shape))
-
-    @property
-    def multi_pod(self) -> bool:
-        return "pod" in self.axes
-
-
-SINGLE_POD = MeshConfig((16, 16), ("data", "model"))
-MULTI_POD = MeshConfig((2, 16, 16), ("pod", "data", "model"))
-
-
 @dataclass(frozen=True)
 class TrainConfig:
     learning_rate: float = 3e-4
@@ -304,19 +231,9 @@ class TrainConfig:
 
 @dataclass(frozen=True)
 class ServeConfig:
-    max_batch: int = 128
     quantize_weights: bool = False       # int8 weight-only serving path
-    kv_cache_dtype: str = "bfloat16"
     serve_fsdp: bool = False             # shard serve weights over data too
     max_seq_len: int = 32768
-
-
-@dataclass(frozen=True)
-class RunConfig:
-    model: ModelConfig
-    mesh: MeshConfig = SINGLE_POD
-    train: TrainConfig = TrainConfig()
-    serve: ServeConfig = ServeConfig()
 
 
 # ---------------------------------------------------------------------------

@@ -3,7 +3,7 @@
 [arXiv:1810.04805; hf:tfhub bert_en_uncased_L-12_H-768_A-12]
 
 Encoder-only => no decode shapes; used by the paper-reproduction benchmark
-suite (Fig 11/12, Table 5), not by the 40-cell dry-run table.
+suite (Fig 11/12, Table 5).
 """
 from repro.config import ModelConfig, register
 

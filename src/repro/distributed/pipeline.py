@@ -8,7 +8,7 @@ stage s -> s+1 via ``lax.ppermute``. Bubble fraction is the usual
 
 This substrate is exercised at smoke scale (multi-device subprocess tests)
 and is available via ``TrainConfig``-level wiring for models whose layers
-are homogeneous; the 40-cell dry-run table uses DP x TP meshes.
+are homogeneous.
 """
 from __future__ import annotations
 

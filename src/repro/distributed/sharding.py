@@ -3,7 +3,7 @@
 Every tensor dimension in the model carries a *logical* name ("batch",
 "heads", "mlp", ...). A :class:`RuleSet` maps logical names to an ordered
 tuple of physical mesh axes. The resolver assigns mesh axes to dims with two
-safety properties that make the 40-cell dry-run robust:
+safety properties:
 
 * **divisibility fallback** — a mesh axis whose size does not divide the dim
   is dropped (e.g. ``kv_heads=10`` over ``model=16`` resolves to replicated),
@@ -58,7 +58,6 @@ def train_rules() -> RuleSet:
         "mlp_act": ("model",),
         "vocab_act": ("model",),
         "expert_act": ("model",),
-        "expert_flat": ("model",),
         "kv_seq": ("model",),
         # params: fsdp over (pod,data), tensor-parallel over model
         "p_vocab": ("model",),
@@ -84,7 +83,6 @@ def serve_rules(serve_fsdp: bool = False, batch1: bool = False) -> RuleSet:
         "mlp_act": ("model",),
         "vocab_act": ("model",),
         "expert_act": ("model",),
-        "expert_flat": ("model",),
         # decode caches: sequence-sharded (flash-decode combine); when
         # batch==1 the data axis is idle, so shard kv_seq over both.
         "kv_seq": ("pod", "data", "model") if batch1 else ("model",),

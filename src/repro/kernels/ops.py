@@ -14,8 +14,7 @@ Two cases stay on the jnp paths on the TPU too:
 * a program traced under a sharding mesh of more than one device
   (:func:`repro.distributed.sharding.use_sharding`). The kernels are not
   wrapped in ``shard_map``, so a sharded program keeps the XLA paths and
-  their layouts (``impl="chunked_kvrep"`` included). On a single chip,
-  ``impl`` and ``chunk`` do nothing.
+  their layouts.
 
 Prefill and the SSD scan take any sequence length: prefill's TPU branch
 pads to the kernel's tile, the SSD scan pads to its chunk on every
@@ -35,7 +34,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.sharding import active_mesh
-from repro.distributed.sharding import shard as _shard
 from repro.kernels import ref as _ref
 from repro.kernels.decode_attention import BLOCK_K as DECODE_BLOCK
 from repro.kernels.decode_attention import decode_attention as _pl_decode
@@ -80,26 +78,18 @@ def cache_len(n: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-def rmsnorm(x, w, eps: float = 1e-5, lowp: bool = False):
-    """``lowp`` chooses between the jnp paths off the TPU."""
+def rmsnorm(x, w, eps: float = 1e-5):
     if on_tpu():
         return _grad_by_ref(functools.partial(_pl_rmsnorm, eps=eps),
                             functools.partial(_ref.rmsnorm_ref, eps=eps),
                             x, w)
-    if lowp:
-        return _ref.rmsnorm_lowp(x, w, eps)
     return _ref.rmsnorm_ref(x, w, eps)
 
 
 def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
-              q_offset: int = 0, kv_len=None, impl: str = "ref",
-              chunk: int = 512):
-    """q: (b, s, hq, d); k, v: (b, s, hkv, d). ``impl`` and ``chunk``
-    choose among the jnp paths; the Pallas kernel ignores them."""
-    if kv_len is not None or q_offset:
-        return _ref.attention_ref(q, k, v, causal=causal, scale=scale,
-                                  q_offset=q_offset, kv_len=kv_len)
-    if on_tpu():
+              q_offset: int = 0, kv_len=None):
+    """q: (b, s, hq, d); k, v: (b, s, hkv, d)."""
+    if on_tpu() and kv_len is None and not q_offset:
         # Pad the sequence to the kernel's block and slice it back. Under
         # the causal mask the padded keys sit after every real query.
         s = q.shape[1]
@@ -114,27 +104,12 @@ def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
             functools.partial(_pl_flash, causal=causal, scale=scale),
             functools.partial(_ref.attention_ref, causal=causal, scale=scale),
             q, k, v)[:, :s]
-    if impl.startswith("chunked"):
-        if impl == "chunked_kvrep":
-            # GQA sharding fix for the XLA path: the (hkv, g) reshape
-            # can't shard either factor over a 16-way model axis, so
-            # scores replicate. Expanding KV to hq heads keeps the
-            # flat head dim sharded (cheap: KV is tiny next to the
-            # O(s^2) scores it de-replicates). The repeat output MUST
-            # be re-constrained or it replicates too.
-            g = q.shape[2] // k.shape[2]
-            if g > 1:
-                k = _shard(jnp.repeat(k, g, axis=2),
-                           ("batch", "seq", "heads_act", None))
-                v = _shard(jnp.repeat(v, g, axis=2),
-                           ("batch", "seq", "heads_act", None))
-        return _ref.attention_chunked(q, k, v, causal=causal,
-                                      scale=scale, chunk=chunk)
-    return _ref.attention_ref(q, k, v, causal=causal, scale=scale)
+    return _ref.attention_ref(q, k, v, causal=causal, scale=scale,
+                              q_offset=q_offset, kv_len=kv_len)
 
 
 def decode_attention(q, k, v, length, layer=None, *,
-                     scale: Optional[float] = None, impl: str = "ref"):
+                     scale: Optional[float] = None):
     """q: (b, hq, d); k, v: the head-major cache, (b, hkv, L, d), or
     stacked over layers, (layers, b, hkv, L, d), with ``layer`` picking
     one; L from :func:`cache_len`. The kernel reads the stacked cache in
@@ -145,9 +120,6 @@ def decode_attention(q, k, v, length, layer=None, *,
             functools.partial(_pl_decode, scale=scale),
             functools.partial(_ref.decode_attention_ref, scale=scale),
             q, k, v, length, layer)
-    if impl == "chunked":   # "chunked" config selects low-cast decode
-        return _ref.decode_attention_lowcast(q, k, v, length, layer,
-                                             scale=scale)
     return _ref.decode_attention_ref(q, k, v, length, layer, scale=scale)
 
 

@@ -25,16 +25,6 @@ def rmsnorm_ref(x: jax.Array, w: jax.Array, eps: float = 1e-5) -> jax.Array:
     return (y * w.astype(jnp.float32)).astype(dt)
 
 
-def rmsnorm_lowp(x: jax.Array, w: jax.Array, eps: float = 1e-5) -> jax.Array:
-    """RMSNorm with fp32 *statistics* but storage-dtype wide ops: the
-    (b, s, d) multiply chain (and its backward) stays bf16; only the
-    per-row variance reduction upcasts. Halves norm HBM traffic."""
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
-                   keepdims=True)
-    inv = jax.lax.rsqrt(var + eps).astype(x.dtype)      # (b, s, 1)
-    return x * inv * w.astype(x.dtype)
-
-
 # ---------------------------------------------------------------------------
 # Attention (prefill / train): GQA, causal or full.
 # q: (b, sq, hq, d)   k, v: (b, skv, hkv, d)
@@ -163,78 +153,6 @@ def ssd_decode_ref(x, dt, A, B, C, D, state):
     y = jnp.einsum("bhpn,bn->bhp", state, C.astype(jnp.float32))
     y = y + xf * D.astype(jnp.float32)[None, :, None]
     return y.astype(x.dtype), state
-
-
-def attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                      causal: bool = True, scale: float | None = None,
-                      chunk: int = 512) -> jax.Array:
-    """Query-chunked attention with native-dtype MXU dots (fp32 accumulation
-    via preferred_element_type, no operand upcasts) and an online softmax.
-
-    The (s x s) score tensor never materializes: peak extra memory is
-    O(chunk x s) per layer instead of O(s^2) — the flash-attention access
-    pattern expressed in pure jnp (the Pallas kernel is the TPU-native
-    version; this path is what the XLA reference build lowers).
-    """
-    b, sq, hq, d = q.shape
-    _, skv, hkv, _ = k.shape
-    g = hq // hkv
-    scale = scale if scale is not None else 1.0 / np.sqrt(d)
-    chunk = min(chunk, sq)
-    assert sq % chunk == 0
-    nc = sq // chunk
-    qr = q.reshape(b, nc, chunk, hkv, g, d)
-    qs = jnp.moveaxis(qr, 1, 0)                      # (nc, b, c, hkv, g, d)
-
-    @jax.checkpoint
-    def one_chunk(args):
-        qc, ci = args
-        s = jax.lax.dot_general(
-            qc, k,
-            (((4,), (3,)), ((0, 2), (0, 2))),
-            preferred_element_type=jnp.float32)       # (b, hkv, c, g, skv)
-        s = s * scale
-        if causal:
-            rows = ci * chunk + jnp.arange(chunk)
-            mask = rows[:, None] >= jnp.arange(skv)[None, :]
-            s = jnp.where(mask[None, None, :, None, :], s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jax.lax.dot_general(
-            p.astype(v.dtype), v,
-            (((4,), (1,)), ((0, 1), (0, 2))),
-            preferred_element_type=jnp.float32)       # (b, hkv, c, g, d)
-        return o.astype(q.dtype)
-
-    outs = jax.lax.map(one_chunk, (qs, jnp.arange(nc)))
-    # (nc, b, hkv, c, g, d) -> (b, s, hq, d)
-    outs = jnp.moveaxis(outs, 0, 1)                   # (b, nc, hkv, c, g, d)
-    outs = jnp.moveaxis(outs, 2, 3)                   # (b, nc, c, hkv, g, d)
-    return outs.reshape(b, sq, hq, d)
-
-
-def decode_attention_lowcast(q: jax.Array, k: jax.Array, v: jax.Array,
-                             length: jax.Array, layer=None, *,
-                             scale: float | None = None) -> jax.Array:
-    """Decode attention without upcasting the KV cache: bf16/fp8 operands
-    feed the dot directly with fp32 accumulation; only the (b, h, skv)
-    scores run in fp32. The cache layout is ``decode_attention_ref``'s."""
-    k, v = _decode_layer(k, v, layer)
-    b, hq, d = q.shape
-    _, hkv, skv, _ = k.shape
-    g = hq // hkv
-    scale = scale if scale is not None else 1.0 / np.sqrt(d)
-    qr = q.reshape(b, hkv, g, d).astype(k.dtype)
-    s = jax.lax.dot_general(
-        qr, k, (((3,), (3,)), ((0, 1), (0, 1))),
-        preferred_element_type=jnp.float32) * scale   # (b, hkv, g, skv)
-    lmask = jnp.arange(skv)[None, None, None, :] < \
-        jnp.asarray(length).reshape(b, 1, 1, 1)
-    s = jnp.where(lmask, s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((3,), (2,)), ((0, 1), (0, 1))),
-        preferred_element_type=jnp.float32)           # (b, hkv, g, d)
-    return o.reshape(b, hq, d).astype(q.dtype)
 
 
 def ssd_chunked(x, dt, A, B, C, D, chunk: int = 256, init_state=None):

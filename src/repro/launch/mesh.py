@@ -1,21 +1,10 @@
-"""Production mesh construction (function — importing this module never
-touches jax device state)."""
+"""Mesh construction (function — importing this module never touches jax
+device state)."""
 from __future__ import annotations
 
 import jax
 
 
-def _mesh(shape, axes):
+def make_mesh(shape, axes):
     return jax.make_mesh(tuple(shape), tuple(axes),
                          axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    return _mesh(shape, axes)

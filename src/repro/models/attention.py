@@ -5,7 +5,8 @@ Modes:
   * train   — full causal self-attention (no cache)
   * prefill — causal self-attention that also emits the KV cache laid out
               in the decode sharding (``kv_seq`` sequence-sharded)
-  * decode  — one new token written at ``pos`` into the cache stacked over
+  * decode  — one new token a slot, written at that slot's position in
+              ``pos`` (shape ``(b,)``) into the cache stacked over
               layers, at the layer's index, and attended against in place
               (flash-decode partial-softmax combine under GSPMD)
 
@@ -113,8 +114,7 @@ def attn_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
             k = rope_apply(k, sin, cos)
         with jax.named_scope("attn_core"):
             out = ops.attention(q, k, v, causal=True,
-                                scale=cfg.attention_multiplier,
-                                impl=cfg.attn_impl, chunk=cfg.attn_chunk)
+                                scale=cfg.attention_multiplier)
         new_cache = None
         if mode == "prefill":
             with jax.named_scope("kv_write"):
@@ -126,50 +126,32 @@ def attn_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
                 new_cache = {"k": shard(kc, spec), "v": shard(vc, spec)}
     else:  # decode
         assert cache is not None and pos is not None and layer is not None
-        pos_arr = jnp.asarray(pos, jnp.int32)
-        per_slot = pos_arr.ndim == 1          # (b,) slot positions
+        pos = jnp.asarray(pos, jnp.int32)                   # (b,)
         q, k, v = _project_qkv(params, cfg, x)              # s == 1
-        cdt = cache["k"].dtype   # cache may be lower-precision (fp8 lever)
+        cdt = cache["k"].dtype
         layer = jnp.asarray(layer, jnp.int32)
-        if per_slot:
-            if cfg.rope:
-                # Per-batch RoPE phases (continuous batching: every slot
-                # is at its own sequence position).
-                sin, cos = rope_table(pos_arr, cfg.resolved_head_dim,
-                                      cfg.rope_theta)       # (b, d/2)
-                sin, cos = sin[:, None], cos[:, None]       # (b, 1, d/2)
-                q = rope_apply(q, sin, cos)
-                k = rope_apply(k, sin, cos)
-            # one row of d per (slot, head): a write of whole (hkv, d)
-            # windows would have XLA lay the cache out head-minor
-            bidx = jnp.arange(b)[:, None]
-            hidx = jnp.arange(k.shape[2])[None, :]
-            at = (layer, bidx, hidx, pos_arr[:, None])
-            with jax.named_scope("kv_write"):
-                k_all = cache["k"].at[at].set(k[:, 0].astype(cdt))
-                v_all = cache["v"].at[at].set(v[:, 0].astype(cdt))
-            length = pos_arr + 1
-        else:
-            if cfg.rope:
-                sin, cos = rope_table(pos_arr.reshape(1),
-                                      cfg.resolved_head_dim, cfg.rope_theta)
-                q = rope_apply(q, sin, cos)
-                k = rope_apply(k, sin, cos)
-            zero = jnp.zeros((), jnp.int32)
-            at = (layer, zero, zero, pos_arr, zero)
-            with jax.named_scope("kv_write"):
-                k_all, v_all = (
-                    jax.lax.dynamic_update_slice(
-                        cache[n], t.transpose(0, 2, 1, 3)[None].astype(cdt),
-                        at)
-                    for n, t in (("k", k), ("v", v)))
-            length = jnp.full((b,), pos_arr + 1, jnp.int32)
+        if cfg.rope:
+            # Per-slot RoPE phases (continuous batching: every slot is at
+            # its own sequence position).
+            sin, cos = rope_table(pos, cfg.resolved_head_dim,
+                                  cfg.rope_theta)           # (b, d/2)
+            sin, cos = sin[:, None], cos[:, None]           # (b, 1, d/2)
+            q = rope_apply(q, sin, cos)
+            k = rope_apply(k, sin, cos)
+        # one row of d per (slot, head): a write of whole (hkv, d)
+        # windows would have XLA lay the cache out head-minor
+        bidx = jnp.arange(b)[:, None]
+        hidx = jnp.arange(k.shape[2])[None, :]
+        at = (layer, bidx, hidx, pos[:, None])
+        with jax.named_scope("kv_write"):
+            k_all = cache["k"].at[at].set(k[:, 0].astype(cdt))
+            v_all = cache["v"].at[at].set(v[:, 0].astype(cdt))
+        length = pos + 1
         stacked = (None, "batch", "kv_heads_act", "kv_seq", None)
         k_all, v_all = shard(k_all, stacked), shard(v_all, stacked)
         with jax.named_scope("attn_core"):
             out1 = ops.decode_attention(q[:, 0], k_all, v_all, length, layer,
-                                        scale=cfg.attention_multiplier,
-                                        impl=cfg.attn_impl)
+                                        scale=cfg.attention_multiplier)
         out = out1[:, None]
         new_cache = {"k": k_all, "v": v_all}
     out = shard(out, ("batch", "seq", "heads_act", None))

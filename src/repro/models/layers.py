@@ -33,9 +33,8 @@ def rmsnorm_specs() -> Params:
     return {"scale": (None,)}
 
 
-def rmsnorm_apply(params: Params, x: jax.Array, eps: float,
-                  lowp: bool = False) -> jax.Array:
-    return ops.rmsnorm(x, params["scale"], eps, lowp=lowp)
+def rmsnorm_apply(params: Params, x: jax.Array, eps: float) -> jax.Array:
+    return ops.rmsnorm(x, params["scale"], eps)
 
 
 # ---------------------------------------------------------------------------
@@ -86,13 +85,10 @@ def mlp_specs() -> Params:
     }
 
 
-def mlp_apply(params: Params, x: jax.Array, lowp: bool = False) -> jax.Array:
+def mlp_apply(params: Params, x: jax.Array) -> jax.Array:
     g = jnp.einsum("bsd,df->bsf", x, params["w_gate"])
     u = jnp.einsum("bsd,df->bsf", x, params["w_up"])
-    if lowp:
-        h = jax.nn.silu(g) * u
-    else:
-        h = (jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype)) * u
+    h = (jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype)) * u
     h = shard(h, ("batch", "seq", "mlp_act"))
     return jnp.einsum("bsf,fd->bsd", h, params["w_down"])
 

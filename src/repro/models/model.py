@@ -84,8 +84,7 @@ def forward(params: Params, cfg: ModelConfig, batch: Dict[str, Any], *,
         params["blocks"], cfg, x, mode=mode, caches=caches, pos=pos,
         scan=scan, remat=remat, max_len=max_len)
     with jax.named_scope("norm"):
-        x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps,
-                          lowp=cfg.mlp_lowp)
+        x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
     with jax.named_scope("lm_head"):
         logits = unembed_apply(params["embed"] if cfg.tie_embeddings
                                else {**params["embed"]}, x)
@@ -118,8 +117,7 @@ def loss_fn(params: Params, cfg: ModelConfig, batch: Dict[str, Any], *,
         x = _embed_inputs(params, cfg, batch)
         x, _, aux = stack.stack_apply(
             params["blocks"], cfg, x, mode="train", scan=scan, remat=remat)
-        x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps,
-                      lowp=cfg.mlp_lowp)
+        x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
         ft = x.shape[1] - labels.shape[1]
         if ft:
             x = x[:, ft:]

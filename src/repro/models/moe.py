@@ -110,7 +110,7 @@ def moe_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
         out, aux = _dropless_apply(params, cfg, x), jnp.zeros((), jnp.float32)
     if "shared" in params:
         with jax.named_scope("shared_expert"):
-            out = out + mlp_apply(params["shared"], x, lowp=cfg.mlp_lowp)
+            out = out + mlp_apply(params["shared"], x)
     return out, aux
 
 
@@ -143,10 +143,7 @@ def _dropless_apply(params: Params, cfg: ModelConfig, x: jax.Array
         xs = xt[token]                                          # (rows, d)
         g = jax.lax.ragged_dot(xs, params["w_gate"], sizes)
         u = jax.lax.ragged_dot(xs, params["w_up"], sizes)
-        if cfg.mlp_lowp:
-            h = jax.nn.silu(g) * u
-        else:
-            h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
+        h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
         y = jax.lax.ragged_dot(h, params["w_down"], sizes)      # (rows, d)
         # rows past the groups are not defined by ragged_dot: select, do
         # not multiply
@@ -195,15 +192,8 @@ def _capacity_apply(params: Params, cfg: ModelConfig, x: jax.Array,
     aux = moe.aux_loss_weight * e * jnp.mean(jnp.sum(me * ce, axis=-1))
 
     # ---- dispatch: k-major position assignment under capacity ----
-    v2 = moe.dispatch == "v2"
-    if v2:
-        # drop-mode scatter straight into the expert-flat buffer: indices
-        # >= e*cap fall off the end (no overflow row), so the buffer's row
-        # dim is exactly e*cap and shards cleanly over the model axis.
-        buf = jnp.zeros((groups, e * cap, d), x.dtype)
-        buf = shard(buf, ("batch", "expert_flat", "embed_act"))
-    else:
-        buf = jnp.zeros((groups, e * cap + 1, d), x.dtype)
+    # dropped tokens go to one extra overflow row past the e*cap buffer
+    buf = jnp.zeros((groups, e * cap + 1, d), x.dtype)
     counts = jnp.zeros((groups, e), jnp.int32)
     dests = []
     keeps = []
@@ -223,36 +213,24 @@ def _capacity_apply(params: Params, cfg: ModelConfig, x: jax.Array,
         dests.append(dest)
         keeps.append(keep)
 
-    xb = (buf if v2 else buf[:, : e * cap]).reshape(groups, e, cap, d)
+    xb = buf[:, : e * cap].reshape(groups, e, cap, d)
     xb = shard(xb, ("batch", "expert_act", None, "embed_act"))
 
     # ---- grouped expert SwiGLU ----
     g_h = jnp.einsum("gecd,edf->gecf", xb, params["w_gate"])
     u_h = jnp.einsum("gecd,edf->gecf", xb, params["w_up"])
-    if cfg.mlp_lowp:
-        h = jax.nn.silu(g_h) * u_h
-    else:
-        h = jax.nn.silu(g_h.astype(jnp.float32)).astype(x.dtype) * u_h
+    h = jax.nn.silu(g_h.astype(jnp.float32)).astype(x.dtype) * u_h
     h = shard(h, ("batch", "expert_act", None, None))
     yb = jnp.einsum("gecf,efd->gecd", h, params["w_down"])
     yb = shard(yb, ("batch", "expert_act", None, "embed_act"))
 
     # ---- combine ----
-    y_flat = yb.reshape(groups, e * cap, d)
-    if v2:
-        y_flat = shard(y_flat, ("batch", "expert_flat", "embed_act"))
-    else:
-        y_flat = jnp.concatenate(
-            [y_flat, jnp.zeros((groups, 1, d), y_flat.dtype)], axis=1)
+    y_flat = jnp.concatenate(
+        [yb.reshape(groups, e * cap, d),
+         jnp.zeros((groups, 1, d), yb.dtype)], axis=1)
     out = jnp.zeros_like(xg)
     for kk in range(k):
-        if v2:
-            # fill-mode take: dropped slots (dest == e*cap) read as zero.
-            y_k = jax.vmap(lambda rows, ix: jnp.take(
-                rows, ix, axis=0, mode="fill", fill_value=0))(
-                    y_flat, dests[kk])
-        else:
-            y_k = y_flat[g_iota, dests[kk]]                     # (g, t, d)
+        y_k = y_flat[g_iota, dests[kk]]                         # (g, t, d)
         w_k = (combine[:, :, kk] * keeps[kk]).astype(x.dtype)
         out = out + y_k * w_k[..., None]
     out = out.reshape(b, s, d)

@@ -5,7 +5,7 @@ finding the smallest *block period* ``p`` such that the per-layer signature
 ``(mixer_kind, ffn_kind)`` repeats with period ``p``; parameters are stacked
 over ``num_layers // p`` repeats and the stack runs as one ``lax.scan`` over
 blocks of ``p`` explicitly-traced layers. This keeps compile time flat in
-depth (one trace per distinct layer signature) for the 40-cell dry-run.
+depth (one trace per distinct layer signature).
 """
 from __future__ import annotations
 
@@ -97,8 +97,7 @@ def layer_apply(params: Params, cfg: ModelConfig, i_sig: Tuple[str, str],
     with this layer's entry updated."""
     kind, ffn = i_sig
     with jax.named_scope("norm"):
-        h = rmsnorm_apply(params["norm1"], x, cfg.norm_eps,
-                          lowp=cfg.mlp_lowp)
+        h = rmsnorm_apply(params["norm1"], x, cfg.norm_eps)
     if kind == ATTN:
         mix, new_cache = attn_mod.attn_apply(
             params["mixer"], cfg, h, mode=mode, cache=cache, pos=pos,
@@ -110,13 +109,12 @@ def layer_apply(params: Params, cfg: ModelConfig, i_sig: Tuple[str, str],
     aux = jnp.zeros((), jnp.float32)
     if ffn != "none":
         with jax.named_scope("norm"):
-            h = rmsnorm_apply(params["norm2"], x, cfg.norm_eps,
-                              lowp=cfg.mlp_lowp)
+            h = rmsnorm_apply(params["norm2"], x, cfg.norm_eps)
         with jax.named_scope("moe" if ffn == "moe" else "mlp"):
             if ffn == "moe":
                 f, aux = moe_mod.moe_apply(params["ffn"], cfg, h, mode=mode)
             else:
-                f = mlp_apply(params["ffn"], h, lowp=cfg.mlp_lowp)
+                f = mlp_apply(params["ffn"], h)
         x = _residual(cfg, x, f)
     x = shard(x, ("batch", "seq", "embed_act"))
     return x, new_cache, aux

@@ -159,6 +159,7 @@ class ServingEngine:
                 nxt = jax.random.categorical(k, logits).astype(jnp.int32)
             out.append(nxt)
             logits, caches = self.decode_fn(
-                self.params, nxt[:, None], caches, pos)
+                self.params, nxt[:, None], caches,
+                jnp.full((b,), pos, jnp.int32))
             pos += 1
         return jnp.stack(out, axis=1)

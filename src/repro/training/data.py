@@ -22,8 +22,6 @@ from typing import Any, Callable, Dict, Iterator, Optional
 import jax
 import numpy as np
 
-from repro.config import ModelConfig, ShapeSpec
-
 
 @dataclass(frozen=True)
 class DataConfig:
@@ -55,19 +53,6 @@ def _gen_batch(cfg: DataConfig, step: int) -> Dict[str, np.ndarray]:
         out["vision_embeds"] = rng.standard_normal(
             (b, cfg.frontend_tokens, cfg.frontend_dim)).astype(np.float32)
     return out
-
-
-def data_config_for(model: ModelConfig, shape: ShapeSpec,
-                    seed: int = 0) -> DataConfig:
-    ft = model.frontend_tokens
-    return DataConfig(
-        vocab_size=model.vocab_size,
-        seq_len=shape.seq_len - ft,
-        global_batch=shape.global_batch,
-        seed=seed,
-        frontend_tokens=ft,
-        frontend_dim=model.frontend_dim or model.d_model,
-    )
 
 
 class PrefetchingLoader:

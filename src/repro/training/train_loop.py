@@ -3,8 +3,8 @@
 ``make_train_step`` closes over (model cfg, train cfg) and returns a pure
 (params, opt_state, batch) -> (params, opt_state, metrics) function. All
 sharding is injected by tracing under ``use_sharding(mesh, train_rules)``
-— the same function lowers for 1 CPU device (smoke tests) and for the
-256/512-chip production meshes (dry-run) unchanged.
+— the same function lowers for 1 CPU device (smoke tests) and for a
+multi-device mesh unchanged.
 """
 from __future__ import annotations
 

@@ -70,8 +70,7 @@ def _decode_cache(rng, shape, dtype, layers):
 @pytest.mark.parametrize("layers", [None, 3], ids=["layer", "stacked"])
 def test_decode_attention_matches_oracle(shape, dtype, layers, rng):
     """Slots of length 1, one block, one block and one, and the whole
-    cache; a stacked cache is read at layer 2. The low-cast jnp path
-    takes the same layout."""
+    cache; a stacked cache is read at layer 2."""
     skv = shape[0]
     q, k, v = _decode_cache(rng, shape, dtype, layers)
     layer = None if layers is None else 2
@@ -79,12 +78,10 @@ def test_decode_attention_matches_oracle(shape, dtype, layers, rng):
     out_ref = ref.decode_attention_ref(q, k, v, length, layer)
     out = decode_attention(q, k, v, length, layer, block_k=DECODE_BLOCK,
                            interpret=True)
-    low = ref.decode_attention_lowcast(q, k, v, length, layer)
     tol = 2e-6 if dtype == jnp.float32 else 2e-2
-    for got in (out, low):
-        np.testing.assert_allclose(np.asarray(got, np.float32),
-                                   np.asarray(out_ref, np.float32),
-                                   atol=tol, rtol=tol)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(out_ref, np.float32),
+                               atol=tol, rtol=tol)
 
 
 @pytest.mark.parametrize("layers", [None, 3], ids=["layer", "stacked"])

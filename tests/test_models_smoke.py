@@ -129,7 +129,7 @@ def test_decode_matches_forward_fp32(arch, layers, scan, per_slot):
 
     lg_pre, caches = prefill_slots(0)
     last = jnp.stack([toks[i, n] for i, n in enumerate(lens)])[:, None]
-    pos = jnp.asarray(lens, jnp.int32) + ft if per_slot else s + ft
+    pos = jnp.asarray(lens, jnp.int32) + ft
     lg_dec, dec_caches = jax.jit(lambda p, t, c, ps: lm.decode_step(
         p, cfg, t, c, ps, scan=scan))(params, last, caches, pos)
     rows = np.arange(b)

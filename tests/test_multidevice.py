@@ -156,7 +156,7 @@ for e in (one, sharded):
     e.init_random(0)
 toks = jax.random.randint(jax.random.key(1), (2, 12), 0, cfg.vocab_size)
 nxt = toks[:, -1:]
-for pos in (jnp.asarray([12, 9], jnp.int32), 12):
+for pos in (jnp.asarray([12, 9], jnp.int32), jnp.full((2,), 12)):
     def run(e):
         _, caches = e.prefill_fn(e.params, {{"tokens": toks}})
         return caches, e.decode_fn(e.params, nxt, caches, pos)

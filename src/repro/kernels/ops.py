@@ -23,7 +23,9 @@ backend, and both slice the result back.
 A ``pallas_call`` has a JVP but no transpose rule, so every kernel here
 is differentiated as its oracle: the backward pass recomputes the
 ``ref.*`` function on the same inputs and takes its VJP. Training on the
-TPU runs the kernels forward and the oracles backward.
+TPU runs the kernels forward and the oracles backward. The SSD decode
+update (:func:`ssd_decode`) writes its state in place and is not
+differentiated: nothing trains through a decode step.
 """
 from __future__ import annotations
 
@@ -41,6 +43,7 @@ from repro.kernels.flash_attention import BLOCK_Q as PREFILL_BLOCK
 from repro.kernels.flash_attention import flash_attention as _pl_flash
 from repro.kernels.int8_matmul import int8_matmul as _pl_int8
 from repro.kernels.rmsnorm import rmsnorm as _pl_rmsnorm
+from repro.kernels.ssd_decode import ssd_decode as _pl_ssd_decode
 from repro.kernels.ssd_scan import ssd_scan as _pl_ssd
 
 
@@ -150,6 +153,20 @@ def ssd(x, dt, A, B, C, D, *, chunk: int = 128):
     else:
         y, state = _ref.ssd_chunked(x, dt, A, B, C, D, chunk=chunk)
     return y[:, :s], state
+
+
+def ssd_decode(x, dt, A, B, C, D, state, layer, live=None):
+    """One-token SSD update. x: (b, h, p); dt: (b, h); B, C: (b, n);
+    state: (layers, b, h, p, n) f32 with ``layer`` picking one; live:
+    (b,) bool, or None for every slot. Returns (y, the stacked state with
+    that layer's entry updated). The kernel updates the live slots' state
+    in place and leaves a finished slot's as it was, with y 0; the oracle
+    updates every slot."""
+    if on_tpu():
+        return _pl_ssd_decode(x, dt, A, B, C, D, state, layer, live)
+    cur = jax.lax.dynamic_index_in_dim(state, layer, keepdims=False)
+    y, new = _ref.ssd_decode_ref(x, dt, A, B, C, D, cur)
+    return y, jax.lax.dynamic_update_index_in_dim(state, new, layer, 0)
 
 
 quantize_int8 = _ref.quantize_int8

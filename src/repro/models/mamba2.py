@@ -2,7 +2,8 @@
 
 Train/prefill run the chunked SSD (``kernels.ops.ssd``: Pallas on TPU,
 sequential-scan oracle on CPU); decode runs the O(1) single-token
-recurrence carrying (conv_state, ssd_state).
+recurrence carrying (conv_state, ssd_state), the SSD part through
+``kernels.ops.ssd_decode`` (on TPU in place, for the live slots only).
 """
 from __future__ import annotations
 
@@ -14,7 +15,6 @@ import jax.numpy as jnp
 from repro.config import ModelConfig
 from repro.distributed.sharding import shard
 from repro.kernels import ops
-from repro.kernels.ref import ssd_decode_ref
 from repro.models.layers import dense_init
 
 Params = Dict[str, Any]
@@ -112,12 +112,14 @@ def _gated_norm(y: jax.Array, z: jax.Array, scale: jax.Array,
 
 @jax.named_scope("mamba")
 def mamba_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
-                mode: str, cache: Optional[Params] = None, layer=None
-                ) -> Tuple[jax.Array, Optional[Params]]:
+                mode: str, cache: Optional[Params] = None, layer=None,
+                live=None) -> Tuple[jax.Array, Optional[Params]]:
     """x: (b, s, d) -> (out, new_cache). In decode, ``cache`` is stacked
-    over layers and ``layer`` indexes it; the state is read at that index
-    and written back there whole. Named scopes: ``mamba`` around it all,
-    ``conv``, ``ssd`` and ``gated_norm`` inside."""
+    over layers and ``layer`` indexes it; the conv window is read at that
+    index and written back there whole, and ``ops.ssd_decode`` updates the
+    SSD state of the ``live`` slots ((b,) bool, None for all) there.
+    Named scopes: ``mamba`` around it all, ``conv``, ``ssd`` and
+    ``gated_norm`` inside."""
     m, di, nh = _dims(cfg)
     n, p = m.d_state, m.headdim
     b, s, d = x.shape
@@ -148,11 +150,10 @@ def mamba_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
             }
     else:  # decode: s == 1
         assert cache is not None and layer is not None
-        cur = jax.tree.map(
-            lambda t: jax.lax.dynamic_index_in_dim(t, layer, keepdims=False),
-            cache)
+        conv = jax.lax.dynamic_index_in_dim(cache["conv"], layer,
+                                            keepdims=False)
         with jax.named_scope("conv"):
-            conv_hist = jnp.concatenate([cur["conv"], xbc], axis=1)
+            conv_hist = jnp.concatenate([conv, xbc], axis=1)
             w, bias = params["conv_w"], params["conv_b"]
             acc = jnp.einsum("bkc,kc->bc", conv_hist.astype(jnp.float32),
                              w.astype(jnp.float32))
@@ -164,13 +165,15 @@ def mamba_apply(params: Params, cfg: ModelConfig, x: jax.Array, *,
         with jax.named_scope("ssd"):
             dt = jax.nn.softplus(dt_raw[:, 0].astype(jnp.float32)
                                  + params["dt_bias"][None])
-            y1, state = ssd_decode_ref(xs, dt, A, B, C, params["D"],
-                                       cur["ssd"])
+            y1, ssd = ops.ssd_decode(xs, dt, A, B, C, params["D"],
+                                     cache["ssd"], layer, live)
         y = y1.reshape(b, 1, di)
-        new_cache = jax.tree.map(
-            lambda t, u: jax.lax.dynamic_update_index_in_dim(
-                t, u.astype(t.dtype), layer, 0),
-            cache, {"conv": conv_hist[:, 1:], "ssd": state})
+        new_cache = {
+            "conv": jax.lax.dynamic_update_index_in_dim(
+                cache["conv"], conv_hist[:, 1:].astype(cache["conv"].dtype),
+                layer, 0),
+            "ssd": ssd,
+        }
 
     with jax.named_scope("gated_norm"):
         y = _gated_norm(y, z, params["norm_scale"], cfg.norm_eps)

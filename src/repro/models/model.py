@@ -76,13 +76,13 @@ def _embed_inputs(params: Params, cfg: ModelConfig, batch: Dict[str, Any]
 def forward(params: Params, cfg: ModelConfig, batch: Dict[str, Any], *,
             mode: str = "train", caches: Optional[List[Params]] = None,
             pos=None, scan: bool = True, remat: str = "none",
-            max_len: Optional[int] = None
+            max_len: Optional[int] = None, live=None
             ) -> Tuple[jax.Array, Optional[List[Params]], jax.Array]:
     """Returns (logits, new_caches, aux_loss)."""
     x = _embed_inputs(params, cfg, batch)
     x, new_caches, aux = stack.stack_apply(
         params["blocks"], cfg, x, mode=mode, caches=caches, pos=pos,
-        scan=scan, remat=remat, max_len=max_len)
+        scan=scan, remat=remat, max_len=max_len, live=live)
     with jax.named_scope("norm"):
         x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
     with jax.named_scope("lm_head"):
@@ -177,12 +177,15 @@ def prefill(params: Params, cfg: ModelConfig, batch: Dict[str, Any], *,
 
 
 def decode_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
-                caches: List[Params], pos, *, scan: bool = True
+                caches: List[Params], pos, *, scan: bool = True, live=None
                 ) -> Tuple[jax.Array, List[Params]]:
-    """tokens: (b, 1). Returns (logits (b, vocab), new caches)."""
+    """tokens: (b, 1); pos: (b,) int32; live: (b,) bool, the slots still
+    decoding (None for all). The Pallas SSD update leaves a finished
+    slot's state as it was; its logits are not to be read. Returns
+    (logits (b, vocab), new caches)."""
     logits, new_caches, _ = forward(
         params, cfg, {"tokens": tokens}, mode="decode", caches=caches,
-        pos=pos, scan=scan)
+        pos=pos, scan=scan, live=live)
     return logits[:, 0], new_caches
 
 

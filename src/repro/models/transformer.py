@@ -90,11 +90,12 @@ def _residual(cfg: ModelConfig, x: jax.Array, branch: jax.Array
 
 def layer_apply(params: Params, cfg: ModelConfig, i_sig: Tuple[str, str],
                 x: jax.Array, *, mode: str, cache: Optional[Params],
-                pos, max_len: Optional[int] = None, layer=None
+                pos, max_len: Optional[int] = None, layer=None, live=None
                 ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
     """In decode mode ``cache`` is stacked over the block repeats and
     ``layer`` is this layer's index into it; the stacked cache comes back
-    with this layer's entry updated."""
+    with this layer's entry updated. ``live`` ((b,) bool, None for every
+    slot) reaches the Mamba layers only."""
     kind, ffn = i_sig
     with jax.named_scope("norm"):
         h = rmsnorm_apply(params["norm1"], x, cfg.norm_eps)
@@ -104,7 +105,8 @@ def layer_apply(params: Params, cfg: ModelConfig, i_sig: Tuple[str, str],
             max_len=max_len, layer=layer)
     else:
         mix, new_cache = mamba_mod.mamba_apply(
-            params["mixer"], cfg, h, mode=mode, cache=cache, layer=layer)
+            params["mixer"], cfg, h, mode=mode, cache=cache, layer=layer,
+            live=live)
     x = _residual(cfg, x, mix)
     aux = jnp.zeros((), jnp.float32)
     if ffn != "none":
@@ -192,7 +194,7 @@ def stack_cache_specs(cfg: ModelConfig) -> List[Optional[Params]]:
 def stack_apply(blocks: List[Params], cfg: ModelConfig, x: jax.Array, *,
                 mode: str, caches: Optional[List[Params]] = None,
                 pos=None, scan: bool = True, remat: str = "none",
-                max_len: Optional[int] = None
+                max_len: Optional[int] = None, live=None
                 ) -> Tuple[jax.Array, Optional[List[Params]], jax.Array]:
     """Run all layers. Returns (x, new_caches, aux_loss_sum).
 
@@ -212,7 +214,7 @@ def stack_apply(blocks: List[Params], cfg: ModelConfig, x: jax.Array, *,
             cache_j = None if block_caches is None else block_caches[j]
             x, nc, aux = layer_apply(
                 block_params[j], cfg, sigs[j], x, mode=mode, cache=cache_j,
-                pos=pos, max_len=max_len, layer=r)
+                pos=pos, max_len=max_len, layer=r, live=live)
             new_caches.append(nc)
             aux_total = aux_total + aux
         return x, new_caches, aux_total

@@ -34,8 +34,9 @@ the yielded dict before exit stay in memory):
 * ``repro.engine.prefill``, ``repro.engine.decode``: the uploads and the
   dispatch of the jitted function (``state_bytes``, for a model with
   Mamba layers: the recurrent state the prefill makes for its slot, or
-  that the decode step reads and writes back for every slot; on decode,
-  for a model with attention layers, ``kv_blocks``: the KV blocks per
+  that the decode step reads and writes back for the live slots, of
+  ``state_bytes_all`` for every slot; on decode, for a model with
+  attention layers, ``kv_blocks``: the KV blocks per
   layer the decode kernel fetches, each slot's up to the one holding its
   new position, of ``kv_blocks_all`` in the cache);
 * ``repro.compile`` (``event``, ``duration_s``).

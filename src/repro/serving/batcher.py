@@ -124,7 +124,8 @@ class ContinuousBatcher:
                 return 0
             self._ensure_caches()
             logits, self.caches = self.engine.decode(
-                self.tokens, self.caches, self.positions)
+                self.tokens, self.caches, self.positions,
+                np.array([a is not None for a in self.active]))
             with obs.OFF if rec is None else rec.span(
                     "repro.batcher.sample"):
                 nxt = np.asarray(jnp.argmax(logits, axis=-1))

@@ -78,11 +78,13 @@ class ServingEngine:
                                   max_len=self.max_len)
 
         def _decode(params, tokens, caches, pos):
+            # a finished slot is uploaded with the token -1 (:meth:`decode`)
             with use_sharding(self.mesh, self.rules):
                 if self.scfg.quantize_weights:
                     params = dequantize_params(params)
                 return lm.decode_step(params, cfg, tokens, caches, pos,
-                                      scan=self.scan)
+                                      scan=self.scan,
+                                      live=tokens[:, 0] >= 0)
 
         self.prefill_fn = jax.jit(_prefill)
         self.decode_fn = jax.jit(_decode, donate_argnums=(2,))
@@ -119,20 +121,26 @@ class ServingEngine:
         return (int(np.sum(-(-lengths // bk))),
                 len(lengths) * (self.max_len // bk))
 
-    def decode(self, tokens: np.ndarray, caches, positions: np.ndarray):
+    def decode(self, tokens: np.ndarray, caches, positions: np.ndarray,
+               live: Optional[np.ndarray] = None):
         """Upload each slot's last token and position and dispatch one
-        decode step; returns (logits, caches). Its span carries
-        ``state_bytes``, the recurrent state of every slot, which the step
-        reads and writes back, and ``kv_blocks`` of ``kv_blocks_all``
-        (:meth:`kv_blocks`)."""
+        decode step; returns (logits, caches). A slot that ``live`` ((b,)
+        bool; None for all) marks finished is uploaded with the token -1:
+        its recurrent state is left as it was and its logits are not to
+        be read. Its span carries ``state_bytes``, the recurrent state of
+        the live slots, which the step reads and writes back, of
+        ``state_bytes_all``, that of every slot, and ``kv_blocks`` of
+        ``kv_blocks_all`` (:meth:`kv_blocks`)."""
         rec = obs.RECORDER
         blocks, blocks_all = (None, None) if rec is None else \
             self.kv_blocks(positions)
+        live = np.ones(len(tokens), bool) if live is None else live
         with obs.OFF if rec is None else rec.span(
                 "repro.engine.decode",
-                state_bytes=self.state_bytes * len(tokens) or None,
+                state_bytes=self.state_bytes * int(np.sum(live)) or None,
+                state_bytes_all=self.state_bytes * len(tokens) or None,
                 kv_blocks=blocks, kv_blocks_all=blocks_all):
-            toks = jnp.asarray(tokens[:, None], jnp.int32)
+            toks = jnp.asarray(np.where(live, tokens, -1)[:, None], jnp.int32)
             pos = jnp.asarray(positions, jnp.int32)
             return self.decode_fn(self.params, toks, caches, pos)
 

@@ -9,6 +9,7 @@ program), at seeded random weights. The expert layer is checked for its
 shares and for dropping nothing; attention for ignoring positions.
 """
 import dataclasses
+import functools
 import sys
 from pathlib import Path
 
@@ -143,6 +144,41 @@ def test_prefill_then_decode_matches_reference(lens):
         # over and is below the gap between the two best logits of most
         # positions, so a wrong factor, position or expert fails it.
         np.testing.assert_allclose(np.stack(o), want, rtol=0, atol=2e-6)
+
+
+def test_kernel_decode_skips_finished_slots(monkeypatch):
+    """The decode step on the kernels' TPU branch (interpret mode) with
+    the middle of three slots finished: the live slots' logits match the
+    oracles' step over every slot, the finished slot's SSD state is left
+    bit for bit, and its logits stay finite."""
+    cfg = tiny()
+    params = lm.init_params(cfg, jax.random.key(5))
+    rng = np.random.default_rng(3)
+    caches = []
+    for n in (21, 40, 33):
+        _, c = lm.prefill(params, cfg, {"tokens": jnp.asarray(
+            rng.integers(0, cfg.vocab_size, (1, n)), jnp.int32)},
+            max_len=64)
+        caches.append(c)
+    caches = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=1), *caches)
+    toks = jnp.asarray([[3], [4], [5]], jnp.int32)
+    pos = jnp.asarray([21, 40, 33], jnp.int32)
+    want, _ = lm.decode_step(params, cfg, toks, caches, pos)
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    for name in ("_pl_rmsnorm", "_pl_decode", "_pl_ssd_decode"):
+        monkeypatch.setattr(ops, name, functools.partial(
+            getattr(ops, name), interpret=True))
+    live = np.array([True, False, True])
+    got, new = lm.decode_step(params, cfg, toks, caches, pos,
+                              live=jnp.asarray(live))
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               rtol=0, atol=1e-5)
+    assert np.isfinite(np.asarray(got)).all()
+    for old, upd in zip(caches, new):
+        if "ssd" in old:
+            np.testing.assert_array_equal(np.asarray(upd["ssd"])[:, 1],
+                                          np.asarray(old["ssd"])[:, 1])
 
 
 # ---------------------------------------------------------------------------
@@ -301,9 +337,12 @@ def test_engine_spans_carry_the_recurrent_state():
     eng.init_random(0)
     with obs.recording() as rec:
         _, cache = eng.prefill(np.arange(9, dtype=np.int32))
-        eng.decode(np.zeros(3, np.int64),
-                   jax.tree.map(lambda t: jnp.concatenate([t] * 3, 1),
-                                cache), np.full(3, 9))
-    got = {s.name: s.args.get("state_bytes") for s in rec.spans}
-    assert got["repro.engine.prefill"] == per_slot
-    assert got["repro.engine.decode"] == 3 * per_slot
+        cache3 = jax.tree.map(lambda t: jnp.concatenate([t] * 3, 1), cache)
+        _, cache3 = eng.decode(np.zeros(3, np.int64), cache3, np.full(3, 9))
+        eng.decode(np.zeros(3, np.int64), cache3, np.full(3, 10),
+                   np.array([True, False, True]))
+    got = [(s.name, s.args.get("state_bytes"), s.args.get("state_bytes_all"))
+           for s in rec.spans if s.name.startswith("repro.engine")]
+    assert got == [("repro.engine.prefill", per_slot, None),
+                   ("repro.engine.decode", 3 * per_slot, 3 * per_slot),
+                   ("repro.engine.decode", 2 * per_slot, 3 * per_slot)]

@@ -1,9 +1,11 @@
-"""Mamba-2 SSD kernel vs the sequential-recurrence oracle."""
+"""Mamba-2 SSD kernels vs the sequential-recurrence oracle: the chunked
+scan of prefill, and the one-token decode update of the live slots."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import ref
+from repro.kernels.ssd_decode import ssd_decode
 from repro.kernels.ssd_scan import ssd_scan
 
 
@@ -67,3 +69,33 @@ def test_ssd_state_decay(rng):
     # state = sum over j of exp(L_last - L_j) dt B x; only the last step
     # survives: bounded by dt * |B| * |x|
     assert np.isfinite(np.asarray(state)).all()
+
+
+LIVE = {"all": [1] * 8, "one": [0, 0, 0, 0, 0, 1, 0, 0],
+        "scattered": [1, 0, 0, 1, 1, 0, 1, 0], "none": [0] * 8}
+
+
+@pytest.mark.parametrize("live", LIVE.values(), ids=LIVE.keys())
+@pytest.mark.parametrize("h,p,n", [(128, 64, 128), (24, 64, 128)],
+                         ids=["granite", "mamba2-130m"])
+def test_ssd_decode_updates_live_slots_in_place(h, p, n, live, rng):
+    """Eight slots at granite-4.0-h-small's and mamba2-130m's head widths,
+    the state stacked over two layers and the second one updated: the
+    live slots' y and state match the oracle, a finished slot's state and
+    the other layer are left bit for bit, and a finished slot's y is 0."""
+    b, layer = len(live), 1
+    x, dt, A, B, C, D = (t[:, 0] if t.ndim > 1 else t
+                         for t in _inputs(rng, b, 1, h, p, n))
+    state = jnp.asarray(rng.standard_normal((2, b, h, p, n)), jnp.float32)
+    lv = np.asarray(live, bool)
+    y_ref, st_ref = ref.ssd_decode_ref(x, dt, A, B, C, D, state[layer])
+    y, st = ssd_decode(x, dt, A, B, C, D, state, layer, jnp.asarray(lv),
+                       interpret=True)
+    y, st, state = map(np.asarray, (y, st, state))
+    np.testing.assert_allclose(y[lv], np.asarray(y_ref)[lv],
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(st[layer][lv], np.asarray(st_ref)[lv],
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(st[layer][~lv], state[layer][~lv])
+    np.testing.assert_array_equal(st[1 - layer], state[1 - layer])
+    assert np.isfinite(y).all() and not y[~lv].any()

@@ -21,6 +21,7 @@ from repro.kernels import ops
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rmsnorm import rmsnorm
+from repro.kernels.ssd_decode import ssd_decode
 from repro.kernels.ssd_scan import ssd_scan
 from repro.models import model as lm
 from repro.training.optimizer import init_opt_state
@@ -209,10 +210,31 @@ def test_ssd_scan_compiles_at_granite_widths(spec):
     assert "%ssd_scan" in text
 
 
+def test_ssd_decode_compiles(spec):
+    """The decode update at granite widths, 64 slots, on the state
+    stacked over 9 layers: the kernel keeps its name and updates the
+    state in place (the whole stack aliases the output, no copy in
+    temp)."""
+    m = get_config("granite-4.0-h-small").mamba
+    b, h, p, n = 64, 128, m.headdim, m.d_state
+    f32 = jnp.float32
+    state = spec((9, b, h, p, n), f32)
+    compiled = jax.jit(ssd_decode, donate_argnums=(6,)).lower(
+        spec((b, h, p)), spec((b, h), f32), spec((h,), f32), spec((b, n)),
+        spec((b, n)), spec((h,), f32), state, spec((), jnp.int32),
+        spec((b,), jnp.bool_)).compile()
+    assert "%ssd_decode" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    state_bytes = state.size * 4
+    assert mem.alias_size_in_bytes == state_bytes
+    assert mem.temp_size_in_bytes < state_bytes / 9 / b
+
+
 def test_granite_decode_step_compiles(tpu_branch, one_chip, spec):
     """The hybrid decode step with its caches (SSD and conv state, KV)
-    donated: every cache aliases the output, and the held experts run as
-    XLA's ragged dot, not as one dense product per expert."""
+    donated and a live-slot vector: every cache aliases the output, the
+    SSD update is the Pallas kernel, and the held experts run as XLA's
+    ragged dot, not as one dense product per expert."""
     cfg = _granite_cut()
     params = _placed(lm.param_shapes(cfg), one_chip)
     caches = _placed(jax.eval_shape(lambda: lm.init_caches(cfg, 4, 512)),
@@ -220,14 +242,15 @@ def test_granite_decode_step_compiles(tpu_branch, one_chip, spec):
     cache_bytes = sum(a.size * a.dtype.itemsize
                       for a in jax.tree.leaves(caches))
 
-    def step(params, tokens, caches, pos):
-        return lm.decode_step(params, cfg, tokens, caches, pos)
+    def step(params, tokens, caches, pos, live):
+        return lm.decode_step(params, cfg, tokens, caches, pos, live=live)
 
     compiled = jax.jit(step, donate_argnums=(2,)).lower(
-        params, spec((4, 1), jnp.int32), caches,
-        spec((4,), jnp.int32)).compile()
+        params, spec((4, 1), jnp.int32), caches, spec((4,), jnp.int32),
+        spec((4,), jnp.bool_)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "ragged" in text
+    assert "%ssd_decode" in text
     assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
 
 

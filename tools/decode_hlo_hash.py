@@ -4,14 +4,15 @@ show that a change leaves a model's compiled decode step unchanged.
     JAX_PLATFORMS=cpu PYTHONPATH=src python tools/decode_hlo_hash.py \
         --arch internlm2-1.8b --slots 16 --max-len 3072
 
-Compiles the engine's decode step (caches donated, the Pallas branch of
-``kernels.ops``) for one chip of a described ``v5e:2x2``, nothing run,
-and prints the sha256 of the compiled HLO text with its debug
-information left out: Python tracebacks are kept out of locations, and
-the file, function and stack-frame tables and every ``metadata={...}``
-are dropped. What is left (instructions, shapes, layouts, schedule and
-the kernels' bodies) changes only if the program does. The kernels'
-bodies name their source files, so compare two trees from one path:
+Compiles the engine's own decode step (``ServingEngine.decode_fn``:
+caches donated, the Pallas branch of ``kernels.ops``) for one chip of a
+described ``v5e:2x2``, nothing run, and prints the sha256 of the
+compiled HLO text with its debug information left out: Python
+tracebacks are kept out of locations, and the file, function and
+stack-frame tables and every ``metadata={...}`` are dropped. What is
+left (instructions, shapes, layouts, schedule and the kernels' bodies)
+changes only if the program does. The kernels' bodies name their source
+files, so compare two trees from one path:
 
     cp -r <tree>/src /tmp/cmp/src
     PYTHONPATH=/tmp/cmp/src python tools/decode_hlo_hash.py ...
@@ -51,9 +52,10 @@ def main() -> None:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from repro.config import get_config
+    from repro.config import ServeConfig, get_config
     from repro.kernels import ops
     from repro.models import model as lm
+    from repro.serving.engine import ServingEngine
 
     jax.config.update("jax_enable_compilation_cache", False)
     jax.config.update("jax_include_full_tracebacks_in_locations", False)
@@ -68,17 +70,13 @@ def main() -> None:
             a.shape, a.dtype, sharding=one), tree)
 
     cfg = get_config(args.arch)
+    engine = ServingEngine(cfg, ServeConfig(max_seq_len=args.max_len))
     params = place(lm.param_shapes(cfg))
     caches = place(jax.eval_shape(lambda: lm.init_caches(
-        cfg, args.slots, ops.cache_len(args.max_len))))
-
-    def _decode(params, tokens, caches, pos):
-        return lm.decode_step(params, cfg, tokens, caches, pos, scan=True)
-
-    compiled = jax.jit(_decode, donate_argnums=(2,)).lower(
-        params, jax.ShapeDtypeStruct((args.slots, 1), jnp.int32, sharding=one),
-        caches, jax.ShapeDtypeStruct((args.slots,), jnp.int32, sharding=one),
-    ).compile()
+        cfg, args.slots, engine.max_len)))
+    tokens, pos = place([jax.ShapeDtypeStruct((args.slots, 1), jnp.int32),
+                         jax.ShapeDtypeStruct((args.slots,), jnp.int32)])
+    compiled = engine.decode_fn.lower(params, tokens, caches, pos).compile()
     print(hashlib.sha256(strip_debug(compiled.as_text()).encode()).hexdigest())
 
 
